@@ -30,6 +30,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     want = set(args.only.split(",")) if args.only else set(SUITES)
     failures = []
     for key, (module, desc) in SUITES.items():

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import time
 
-from . import engine
+from . import engine, hwprobe
 from .cache import ArtifactCache
 from .model import GenerationResult, Selection
 from .select import hardware_flags, score, valid_candidates
@@ -83,9 +83,12 @@ class BenchSelectGPO:
         if ctx.errors:
             return ctx
         tgt = ctx.targets[ctx.config.target]
-        if not tgt.runs_on_host:
+        if not hwprobe.runnable(tgt):
             ctx.warn("bench-select: target does not run on this host; skipped")
             return ctx
+        # on the live device's own target a candidate that fails is a fault
+        # in the library that device runs, not a slow candidate
+        live = tgt.name == hwprobe.live_target()
         hw = hardware_flags(ctx)
         from .library import artifact_key
 
@@ -125,8 +128,14 @@ class BenchSelectGPO:
                         try:
                             fn = _compile_candidate(ctx, prim, impl, ctype)
                             t = _time_candidate(fn, args, n_iter)
-                        except Exception as e:  # candidate broken on host
-                            ctx.warn(f"bench-select {key}: candidate failed ({e})")
+                        except Exception as e:
+                            msg = (f"bench-select {key}: candidate "
+                                   f"def[{prim.definitions.index(impl)}] "
+                                   f"failed ({e})")
+                            if live:
+                                ctx.fail(msg)
+                            else:   # an emulated dialect: rank it last
+                                ctx.warn(msg)
                             t = float("inf")
                         times.append(t)
                     winner_idx = prim.definitions.index(

@@ -121,8 +121,10 @@ def _diff_bench_winners(trajectory: dict, fresh: dict) -> list[str]:
 
 
 def _cmd_bench(args) -> int:
-    """Warm bench-selection winners for every host-runnable target and emit a
+    """Warm bench-selection winners for every target that runs here (the
+    host-runnable dialects and the live device's own target) and emit a
     JSON report of winners per (target, primitive, hardware key)."""
+    from . import hwprobe
     from .corpus import load_corpus
     from .library import (DEFAULT_BUILD_ROOT, artifact_key, generate_library)
     from .cache import ArtifactCache
@@ -136,14 +138,15 @@ def _cmd_bench(args) -> int:
         if unknown:
             print(f"error: unknown target(s) {unknown}", file=sys.stderr)
             return 2
-        not_host = [t for t in names if not corpus.targets[t].runs_on_host]
+        not_host = [t for t in names
+                    if not hwprobe.runnable(corpus.targets[t])]
         if not_host:
             print(f"error: target(s) {not_host} do not run on this host",
                   file=sys.stderr)
             return 2
     else:
         names = [t for t in sorted(corpus.targets)
-                 if corpus.targets[t].runs_on_host]
+                 if hwprobe.runnable(corpus.targets[t])]
     build_root = Path(args.build_root) if args.build_root else DEFAULT_BUILD_ROOT
     store = ArtifactCache(build_root)
     report: dict = {"smoke": args.smoke, "targets": {}}
@@ -271,11 +274,12 @@ def build_parser() -> argparse.ArgumentParser:
     c.set_defaults(fn=_cmd_corpus)
 
     b = sub.add_parser(
-        "bench", help="warm bench-selection winners for host-runnable targets")
+        "bench", help="warm bench-selection winners for targets that run here")
     _add_common(b)
     b.add_argument("--targets", action="append", default=[],
-                   help="comma-separated host-runnable targets "
-                        "(default: every runs_on_host target)")
+                   help="comma-separated targets that run here "
+                        "(default: every runs_on_host target plus the "
+                        "live device's own)")
     b.add_argument("--report", nargs="?", const="__root__", default=None,
                    help="write the JSON winners report: with PATH, one "
                         "combined file there; bare, one BENCH_<target>.json "

@@ -42,6 +42,8 @@ _LOGICAL_TO_PHYSICAL = {
 # mesh introspection helpers (work on jax.sharding.Mesh AND shape-only fakes)
 
 def _axis_sizes(mesh) -> dict[str, int]:
+    if isinstance(mesh, jax.sharding.AbstractMesh):   # inside a trace
+        return dict(zip(mesh.axis_names, mesh.axis_sizes))
     return dict(zip(tuple(mesh.axis_names), tuple(np.shape(mesh.devices))))
 
 def _data_axes(mesh) -> tuple[str, ...]:
@@ -235,21 +237,24 @@ def batch_shardings(mesh, batch):
 # ---------------------------------------------------------------------------
 # in-graph logical constraints
 
-def _ambient_mesh():
-    try:
-        from jax.interpreters import pxla
+def ambient_mesh():
+    """The mesh installed by ``jax.set_mesh`` (None when unmeshed); inside
+    a trace only its abstract form is known, which is all a sharding
+    constraint needs."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
-    return None
+
+def dim_entry(mesh, dim: int, *, model: bool = False):
+    """The PartitionSpec entry a dimension of size ``dim`` takes: ``model``,
+    or the folded data-parallel group; None where the guard unshards it."""
+    entry = "model" if model else _data_entry(mesh)
+    return _guard(mesh, (dim,), (entry,))[0]
 
 
 def ambient_dp_size() -> int:
     """Total data-parallel size of the ambient mesh (1 when unmeshed)."""
-    mesh = _ambient_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         return 1
     return _entry_size(mesh, _data_entry(mesh))
@@ -260,7 +265,7 @@ def logical_constraint(x, *axes):
 
     A no-op outside a mesh context, and per-dim a no-op when the mesh does not
     divide that dim — safe to sprinkle on every residual boundary."""
-    mesh = _ambient_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         return x
     entries = []

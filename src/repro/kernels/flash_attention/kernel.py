@@ -15,7 +15,10 @@ exactly the waste the GPU implementations avoid with shared memory, adapted
 here to VMEM reuse.
 
 Backward (FlashAttention-2 style recomputation): the forward additionally
-emits per-row logsumexp residuals ``lse = m + log(l)`` of shape (B, H, Sq),
+emits per-row logsumexp residuals ``lse = m + log(l)`` of shape
+(B, H, Sq, 1) — a trailing unit lane axis, so the (1, 1, bq, 1) residual
+block meets the TPU tiling rule (second-minor a multiple of 8, minor equal
+to the array's),
 so the backward never re-materializes the (Sq, Sk) score matrix — each tile
 is recomputed as ``p = exp(s - lse)`` and immediately contracted away:
 
@@ -121,8 +124,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs, scale: float,
         o = jnp.where(l > 0.0, o, 0.0)
         o_ref[0, 0] = o.astype(o_ref.dtype)
         if lse_ref is not None:
-            m = m_scr[:, 0]
-            lv = l_scr[:, 0]
+            m = m_scr[:, :1]
+            lv = l_scr[:, :1]
             # fully-masked rows: lse := 0 keeps the backward's
             # exp(NEG_INF - lse) at exactly 0 instead of NaN
             lse_ref[0, 0] = jnp.where(lv > 0.0, m + jnp.log(jnp.maximum(lv, 1e-30)),
@@ -154,9 +157,9 @@ def _fa_call(q, k, v, *, causal, scale, kv_len, q_offset, block_q, block_k,
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     out_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0))
     if emit_lse:
-        out_shape = [out_shape, jax.ShapeDtypeStruct((b, h, sq), jnp.float32)]
+        out_shape = [out_shape, jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)]
         out_spec = [out_spec,
-                    pl.BlockSpec((1, 1, bq), lambda b_, h_, qi, ki: (b_, h_, qi))]
+                    pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, qi, ki: (b_, h_, qi, 0))]
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -174,7 +177,7 @@ def _fa_call(q, k, v, *, causal, scale, kv_len, q_offset, block_q, block_k,
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running denominator
             pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -198,7 +201,7 @@ def flash_attention_fwd_4d(q, k, v, *, causal: bool = True,
                            scale: float | None = None, kv_len: int | None = None,
                            q_offset: int | None = None, block_q: int = 512,
                            block_k: int = 512, interpret: bool = False):
-    """Forward that also returns the (B, H, Sq) f32 logsumexp residual — the
+    """Forward that also returns the (B, H, Sq, 1) f32 logsumexp residual — the
     only extra state the recomputation backward needs (O(Sq), not O(Sq·Sk))."""
     return _fa_call(q, k, v, causal=causal, scale=scale, kv_len=kv_len,
                     q_offset=q_offset, block_q=block_q, block_k=block_k,
@@ -225,8 +228,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)           # (bk, D)
         v = v_ref[0, 0].astype(jnp.float32)           # (bk, D)
         do = do_ref[0, 0].astype(jnp.float32)         # (bq, D)
-        lse = lse_ref[0, 0][:, None]                  # (bq, 1)
-        delta = delta_ref[0, 0][:, None]              # (bq, 1)
+        lse = lse_ref[0, 0]                           # (bq, 1)
+        delta = delta_ref[0, 0]                       # (bq, 1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -270,8 +273,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)           # (bk, D)
         v = v_ref[0, 0].astype(jnp.float32)           # (bk, D)
         do = do_ref[0, 0].astype(jnp.float32)         # (bq, D)
-        lse = lse_ref[0, 0][:, None]                  # (bq, 1)
-        delta = delta_ref[0, 0][:, None]              # (bq, 1)
+        lse = lse_ref[0, 0]                           # (bq, 1)
+        delta = delta_ref[0, 0]                       # (bq, 1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -304,7 +307,7 @@ def flash_attention_bwd_dq_4d(q, k, v, do, lse, delta, *, causal: bool = True,
                               block_k: int = 512, interpret: bool = False):
     """dq, q-tiled: grid (B, H, Sq/bq, Sk/bk), sequential k accumulation.
 
-    ``lse``/``delta``: (B, H, Sq) f32 residuals. Shapes pre-padded."""
+    ``lse``/``delta``: (B, H, Sq, 1) f32 residuals. Shapes pre-padded."""
     b, h, kh, sq, sk, d, group, bq, bk, scale, kv_len, q_offset = _prep(
         q, k, block_q, block_k, scale, kv_len, q_offset)
     grid = (b, h, sq // bq, sk // bk)
@@ -314,7 +317,7 @@ def flash_attention_bwd_dq_4d(q, k, v, do, lse, delta, *, causal: bool = True,
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, d),
                            lambda b_, h_, qi, ki, g=group: (b_, h_ // g, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b_, h_, qi, ki: (b_, h_, qi))
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, qi, ki: (b_, h_, qi, 0))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -322,7 +325,7 @@ def flash_attention_bwd_dq_4d(q, k, v, do, lse, delta, *, causal: bool = True,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -349,7 +352,7 @@ def flash_attention_bwd_dkv_4d(q, k, v, do, lse, delta, *, causal: bool = True,
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, ki, qi: (b_, h_, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, d),
                            lambda b_, h_, ki, qi, g=group: (b_, h_ // g, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b_, h_, ki, qi: (b_, h_, qi))
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, ki, qi: (b_, h_, qi, 0))
     dkv_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, ki, qi: (b_, h_, ki, 0))
     dkv_shape = jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32)
     return pl.pallas_call(
@@ -360,7 +363,7 @@ def flash_attention_bwd_dkv_4d(q, k, v, do, lse, delta, *, causal: bool = True,
         out_shape=[dkv_shape, dkv_shape],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

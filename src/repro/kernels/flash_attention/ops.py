@@ -17,44 +17,67 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..common import pad_to
+from ..common import heads_spec, pad_to, per_shard
 from . import kernel, ref
+
+
+def _specs(q, k, n_in: int, n_out: int):
+    """Per-shard specs: batch on the data axes, query and KV heads on
+    ``model`` together (each shard keeps whole GQA groups)."""
+    def specs(mesh):
+        s = heads_spec(mesh, q.shape[0], q.shape[1], k.shape[1])
+        return (s,) * n_in, (s,) * n_out if n_out > 1 else s
+    return specs
+
+
+def _fwd_local(q, k, v, *, causal, scale, kv_len, block_q, block_k,
+               interpret, emit_lse):
+    qp, _ = pad_to(q, 2, block_q)
+    kp, _ = pad_to(k, 2, block_k)
+    vp, _ = pad_to(v, 2, block_k)
+    call = kernel.flash_attention_fwd_4d if emit_lse else kernel.flash_attention_4d
+    res = call(qp, kp, vp, causal=causal, scale=scale, kv_len=kv_len,
+               q_offset=kv_len - q.shape[2], block_q=block_q,
+               block_k=block_k, interpret=interpret)
+    sq = q.shape[2]
+    if emit_lse:
+        return res[0][:, :, :sq], res[1][:, :, :sq]
+    return res[:, :, :sq]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
 def _fa(causal, scale, kv_len, block_q, block_k, interpret, q, k, v):
-    qp, _ = pad_to(q, 2, block_q)
-    kp, _ = pad_to(k, 2, block_k)
-    vp, _ = pad_to(v, 2, block_k)
-    out = kernel.flash_attention_4d(
-        qp, kp, vp, causal=causal, scale=scale, kv_len=kv_len,
-        q_offset=kv_len - q.shape[2], block_q=block_q, block_k=block_k,
-        interpret=interpret)
-    return out[:, :, :q.shape[2]]
+    body = partial(_fwd_local, causal=causal, scale=scale, kv_len=kv_len,
+                   block_q=block_q, block_k=block_k, interpret=interpret,
+                   emit_lse=False)
+    return per_shard(body, _specs(q, k, 3, 1))(q, k, v)
 
 
 def _fa_fwd(causal, scale, kv_len, block_q, block_k, interpret, q, k, v):
-    qp, _ = pad_to(q, 2, block_q)
-    kp, _ = pad_to(k, 2, block_k)
-    vp, _ = pad_to(v, 2, block_k)
-    out, lse = kernel.flash_attention_fwd_4d(
-        qp, kp, vp, causal=causal, scale=scale, kv_len=kv_len,
-        q_offset=kv_len - q.shape[2], block_q=block_q, block_k=block_k,
-        interpret=interpret)
-    sq = q.shape[2]
+    body = partial(_fwd_local, causal=causal, scale=scale, kv_len=kv_len,
+                   block_q=block_q, block_k=block_k, interpret=interpret,
+                   emit_lse=True)
+    out, lse = per_shard(body, _specs(q, k, 3, 2))(q, k, v)
     # residuals are O(Sq) per head: inputs + out + logsumexp — no S×S tensor
-    return out[:, :, :sq], (q, k, v, out[:, :, :sq], lse[:, :, :sq])
+    return out, (q, k, v, out, lse)
 
 
-def _fa_bwd_kernels(q, k, v, g, out, lse, *, causal, scale, kv_len,
-                    block_q, block_k, interpret):
+def _fa_bwd_kernels(q, k, v, g, out, lse, **kw):
+    """(dq, dk, dv), one shard at a time under a multi-device mesh."""
+    body = partial(_bwd_local, **kw)
+    return per_shard(body, _specs(q, k, 6, 3))(q, k, v, g, out, lse)
+
+
+def _bwd_local(q, k, v, g, out, lse, *, causal, scale, kv_len,
+               block_q, block_k, interpret):
     """Shared backward body: pad to block multiples, run dq + dk/dv kernels,
     reduce GQA head groups, slice back to logical shapes."""
     b, h, sq, d = q.shape
     _, kh, sk, _ = k.shape
     group = h // kh
     q_offset = kv_len - sq
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+                    keepdims=True)
     qp, _ = pad_to(q, 2, block_q)
     gp, _ = pad_to(g.astype(q.dtype), 2, block_q)
     lsep, _ = pad_to(lse, 2, block_q)

@@ -19,7 +19,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ..common import heads_spec, per_shard
 from . import kernel, ref
 
 
@@ -39,22 +41,35 @@ def paged_attention(q, k_pool, v_pool, tables, kv_len, *, k_scale=None,
                     interpret: bool = False):
     """q (B,H,SQ,D); k_pool/v_pool (KH, n_pages, page, D); tables (B,P) int32
     page ids; kv_len (B,) int32 (scalars broadcast). Returns (B,H,SQ,D)."""
-    b, h, sq, d = q.shape
+    b, h = q.shape[:2]
     kh, _, page, _ = k_pool.shape
-    group = h // kh
     kvl = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
     if k_scale is not None:
         return ref.paged_attention_ref(q, k_pool, v_pool, tables, kvl,
                                        k_scale=k_scale, v_scale=v_scale,
                                        scale=scale)
     bk = block_k if (block_k <= page and page % block_k == 0) else page
-    rq = group * sq
-    q4 = _sublane_pad(q.reshape(b, kh, rq, d))
-    out = kernel.paged_attention_4d(
-        q4, k_pool.reshape(kh, -1, d), v_pool.reshape(kh, -1, d),
-        tables, kvl, sq=sq, page=page, block_k=bk, scale=scale,
-        interpret=interpret)
-    return out[:, :, :rq].reshape(b, h, sq, d)
+
+    def local(q, k_pool, v_pool, tables, kvl):
+        b, h, sq, d = q.shape
+        kh = k_pool.shape[0]
+        rq = (h // kh) * sq
+        q4 = _sublane_pad(q.reshape(b, kh, rq, d))
+        out = kernel.paged_attention_4d(
+            q4, k_pool.reshape(kh, -1, d), v_pool.reshape(kh, -1, d),
+            tables, kvl, sq=sq, page=page, block_k=bk, scale=scale,
+            interpret=interpret)
+        return out[:, :, :rq].reshape(b, h, sq, d)
+
+    def specs(mesh):
+        # slots on the data axes, KV heads (with their query heads) on
+        # ``model``: each shard reads whole pages of its own heads
+        qs = heads_spec(mesh, b, h, kh)
+        pool = P(qs[1], None, None, None)
+        rows = P(qs[0])
+        return (qs, pool, pool, P(qs[0], None), rows), qs
+
+    return per_shard(local, specs)(q, k_pool, v_pool, tables, kvl)
 
 
 __all__ = ["paged_attention", "ref"]

@@ -4,9 +4,10 @@ data model can carry as a specialized variant).
 
 The paper's SIMD loop (load -> between_inclusive -> mask->int -> add, then a
 final hadd) becomes: grid over (rows/bm) VMEM tiles of a (rows, 128) view;
-each step counts in-range lanes of its tile on the VPU and accumulates into a
-lane-replicated SMEM-resident running count via an output block revisited at
-every grid step (index_map constant), written once at the final step.
+each step counts in-range lanes of its tile on the VPU and accumulates
+per-lane counts in a (1, 128) VMEM scratch row; the final step reduces the
+row and writes the total, lane-replicated, into a (1, 128) output block (a
+vector store: the TPU cannot store a scalar to VMEM).
 
 The finalization `hadd` of Fig 8/9 is the in-tile jnp.sum reduction — on TPU
 the adder tree of Fig 11 is what the VPU cross-lane reduction emits anyway
@@ -34,8 +35,8 @@ def _range_count_kernel(x_ref, lo_ref, hi_ref, o_ref, acc_scr, *, n_valid: int,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     x = x_ref[...]
-    lo = lo_ref[0, 0]
-    hi = hi_ref[0, 0]
+    lo = lo_ref[...]                                    # (1, 1), broadcast
+    hi = hi_ref[...]
     # global element index of each lane, to mask the tail padding
     row0 = i * bm
     pos = (row0 + jax.lax.broadcasted_iota(jnp.int32, (bm, lanes), 0)) * lanes \
@@ -46,7 +47,8 @@ def _range_count_kernel(x_ref, lo_ref, hi_ref, o_ref, acc_scr, *, n_valid: int,
 
     @pl.when(i == n - 1)
     def _finalize():
-        o_ref[0, 0] = jnp.sum(acc_scr[...])
+        total = jnp.sum(acc_scr[...], axis=-1, keepdims=True)   # (1, 1)
+        o_ref[...] = jnp.broadcast_to(total, o_ref.shape)
 
 
 def range_count_2d(x2, low, high, *, n_valid: int, block_rows: int = 512,
@@ -67,10 +69,10 @@ def range_count_2d(x2, low, high, *, n_valid: int, block_rows: int = 512,
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, lanes), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, lanes), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, lanes), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

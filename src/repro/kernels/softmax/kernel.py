@@ -31,7 +31,7 @@ def softmax_2d(x, *, block_rows: int = 256, interpret: bool = False):
         in_specs=[pl.BlockSpec((bm, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -32,7 +32,7 @@ def swiglu_2d(gate, up, *, block_rows: int = 256, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(gate.shape, gate.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -40,7 +40,7 @@ def to_integral_2d(mask8, *, n: int, block_rows: int = 512,
         in_specs=[pl.BlockSpec((bm, n_pad), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.uint32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

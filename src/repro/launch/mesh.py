@@ -8,17 +8,25 @@ XLA_FLAGS first.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the program places arrays with NamedSharding and
+    # with_sharding_constraint and lets the partitioner propagate the rest
+    # (jax.make_mesh's default, Explicit, would type every array's sharding)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(dp: int = 1, tp: int = 1):
-    """Small mesh over the real local devices (tests / examples)."""
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    """Mesh over the first ``dp * tp`` local devices."""
+    return _auto_mesh((dp, tp), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

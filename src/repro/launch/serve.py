@@ -2,7 +2,8 @@
 continuous batching with chunked prefill — prompts are padded to
 UPD-declared length buckets, prefill advances one fixed-size chunk per
 unified step alongside decode, admission is cost-model gated, and sampling
-is configurable).
+is configurable). Slot memory is paged, and KV-family decode reads the page
+pools directly (fused paged attention).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced \
         --batch 4 --prompt-len 32 --gen-len 32 --requests 8 \
@@ -17,8 +18,44 @@ import json
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
-from repro.serve import BucketPolicy, Request, SamplingConfig, ServeEngine
+from repro.serve import (BucketPolicy, PagedConfig, Request, SamplingConfig,
+                         ServeEngine)
+
+
+def build_engine(cfg, *, batch: int, prompt_len: int, gen_len: int,
+                 sampling: SamplingConfig, seed: int = 0,
+                 prefill_chunk: int | None = None, mesh=None) -> ServeEngine:
+    """The launcher's engine: paged fused slot memory, a slot table sized
+    for ``prompt_len`` + ``gen_len``, optionally sharded over ``mesh``."""
+    # budget the slot table for the decode prefix (vlm vision rows) AND the
+    # length bucket the prompt pads to, or admission would refuse every
+    # request by construction; a prompt beyond the largest declared bucket
+    # extends the bucket set (rounded to whole chunks) instead of refusing
+    policy = BucketPolicy.from_upd(chunk=prefill_chunk)
+    bucket = policy.assign(prompt_len)
+    buckets = None
+    if bucket is None:
+        bucket = BucketPolicy.round_up(prompt_len, policy.chunk)
+        buckets = policy.buckets + (bucket,)
+    return ServeEngine(
+        cfg, batch=batch, max_len=cfg.decode_prefix + bucket + gen_len,
+        sampling=sampling, seed=seed, prefill_chunk=prefill_chunk,
+        buckets=buckets, paged=PagedConfig(), mesh=mesh,
+        enc_len=prompt_len if cfg.family == "audio" else None)
+
+
+def make_requests(cfg, n: int, *, prompt_len: int, gen_len: int,
+                  seed: int = 0, sla_s: float | None = None) -> list[Request]:
+    """``n`` requests of random prompt tokens drawn from ``seed``."""
+    rng = np.random.default_rng(seed)
+    return [
+        Request(rid=f"req{i}",
+                tokens=rng.integers(0, cfg.vocab, prompt_len).astype(np.int32),
+                gen_len=gen_len, sla_s=sla_s)
+        for i in range(n)
+    ]
 
 
 def main(argv=None) -> dict:
@@ -44,6 +81,7 @@ def main(argv=None) -> dict:
                          "up to whole chunks)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -51,34 +89,16 @@ def main(argv=None) -> dict:
     # function ids from a previous model cannot alias stale executables
     jax.clear_caches()
 
-    # budget the slot table for the decode prefix (vlm vision rows) AND the
-    # length bucket the prompt pads to, or admission would refuse every
-    # request by construction; a prompt beyond the largest declared bucket
-    # extends the bucket set (rounded to whole chunks) instead of refusing
-    policy = BucketPolicy.from_upd(chunk=args.prefill_chunk)
-    bucket = policy.assign(args.prompt_len)
-    buckets = None
-    if bucket is None:
-        bucket = BucketPolicy.round_up(args.prompt_len, policy.chunk)
-        buckets = policy.buckets + (bucket,)
-    engine = ServeEngine(
-        cfg, batch=args.batch,
-        max_len=cfg.decode_prefix + bucket + args.gen_len,
+    engine = build_engine(
+        cfg, batch=args.batch, prompt_len=args.prompt_len,
+        gen_len=args.gen_len,
         sampling=SamplingConfig(temperature=args.temperature,
                                 top_k=args.top_k),
-        seed=args.seed,
-        prefill_chunk=args.prefill_chunk, buckets=buckets,
-        enc_len=args.prompt_len if cfg.family == "audio" else None)
-
-    rng = np.random.default_rng(args.seed)
+        seed=args.seed, prefill_chunk=args.prefill_chunk)
     sla_s = args.sla_ms / 1e3 if args.sla_ms is not None else None
-    requests = [
-        Request(rid=f"req{i}",
-                tokens=rng.integers(0, cfg.vocab, args.prompt_len
-                                    ).astype(np.int32),
-                gen_len=args.gen_len, sla_s=sla_s)
-        for i in range(args.requests)
-    ]
+    requests = make_requests(cfg, args.requests, prompt_len=args.prompt_len,
+                             gen_len=args.gen_len, seed=args.seed,
+                             sla_s=sla_s)
 
     report = engine.run(requests)
     first = report["outputs"].get("req0", [])

@@ -21,6 +21,7 @@ import time
 import jax
 
 from repro.ckpt.checkpoint import Checkpointer
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataState, Prefetcher, SyntheticTokens
 from repro.dist import sharding
@@ -73,6 +74,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -86,7 +88,7 @@ def main(argv=None) -> dict:
                                  compress_grads=args.compress_grads, mesh=mesh)
 
     key = jax.random.PRNGKey(args.seed)
-    with mesh:
+    with jax.set_mesh(mesh):
         state = init_train_state(model, opt_cfg, key, mesh=mesh)
 
     data_state = DataState()
@@ -107,7 +109,7 @@ def main(argv=None) -> dict:
     jstep = jax.jit(train_step, donate_argnums=(0,))
     losses = []
     t_start = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             t0 = time.perf_counter()
             batch = prefetch.get()
@@ -138,6 +140,7 @@ def main(argv=None) -> dict:
         "arch": cfg.name,
         "first_loss": losses[0] if losses else None,
         "final_loss": losses[-1] if losses else None,
+        "losses": losses,
         "steps": args.steps,
         "tokens_per_s": tokens / wall,
         "stragglers": watchdog.stragglers,

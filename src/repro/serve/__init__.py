@@ -25,7 +25,7 @@ See README.md in this directory for the slot/state-surgery contract.
 
 from .engine import SamplingConfig, ServeEngine
 from .paging import (PagedConfig, PagedKVStore, PrefixStore, prefix_key,
-                     selected_page_size, upd_page_defaults)
+                     selected_page_size)
 from .scheduler import (BucketPolicy, CostModelAdmission, PagedAdmission,
                         Request, RequestMetrics, Scheduler,
                         upd_serve_defaults)
@@ -58,7 +58,6 @@ __all__ = [
     "prefix_key",
     "selected_page_size",
     "take_slot",
-    "upd_page_defaults",
     "upd_serve_defaults",
     "upd_verify_defaults",
     "validate_donor",

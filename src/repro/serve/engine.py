@@ -66,16 +66,6 @@ from .slots import PagesExhausted, assert_span_fits, validate_donor
 from .spec import (SpeculationConfig, SpeculationPolicy, accept_span,
                    build_drafter, upd_verify_defaults)
 
-# Sharding-invariant RNG: the legacy threefry lowering draws DIFFERENT bits
-# when its operand arrives sharded, so a sampled run on a mesh would diverge
-# from the 1-device engine at the first categorical draw. The partitionable
-# lowering is counter-based per element — same key, same draws, any layout —
-# which is what makes the mesh equivalence guarantee hold for sampled
-# requests too. Set once at import so meshed and unmeshed engines in one
-# process share a single stream (the flag changes sampled streams vs older
-# releases; tests only compare within-process).
-jax.config.update("jax_threefry_partitionable", True)
-
 
 @dataclass(frozen=True)
 class SamplingConfig:
@@ -142,8 +132,10 @@ class ServeEngine:
         self.mesh = mesh
         self._reshard_events = 0
         if mesh is not None:
+            # donate: the unsharded copy on the default device is freed now
             self.params = jax.device_put(
-                self.params, dist_sharding.param_shardings(mesh, self.params))
+                self.params, dist_sharding.param_shardings(mesh, self.params),
+                donate=True)
         # the slot cache is filled to prompt_len + decode_prefix (vlm vision
         # rows), and decode must write AFTER it
         self._prefix = cfg.decode_prefix
@@ -260,11 +252,13 @@ class ServeEngine:
             self._pool_shardings = self._pool_sharding_rules()
             for n in self._store.pools:
                 self._store.pools[n] = jax.device_put(
-                    self._store.pools[n], self._pool_shardings[n])
+                    self._store.pools[n], self._pool_shardings[n],
+                    donate=True)
             for n in self._store.scale_pools:
                 key = f"{n}__scale"
                 self._store.scale_pools[n] = jax.device_put(
-                    self._store.scale_pools[n], self._pool_shardings[key])
+                    self._store.scale_pools[n], self._pool_shardings[key],
+                    donate=True)
         # fused-path counters for report["paged"]
         self._lane_activations = 0      # full page->lane gathers (fallback)
         self._tail_restores = 0         # fused activations (tails only)
@@ -487,7 +481,8 @@ class ServeEngine:
         donor = self.model.init_decode_state(1, self._state_len,
                                              enc_len=self.enc_len)
         if self.mesh is not None:
-            donor = jax.device_put(donor, self._state_shardings(donor))
+            donor = jax.device_put(donor, self._state_shardings(donor),
+                                   donate=True)
         return donor
 
     def _check_steady_sharding(self, state, pools: dict | None = None):
@@ -519,7 +514,8 @@ class ServeEngine:
             state = {n: state[n] for n, ax in self._page_axes.items()
                      if ax is None}
         if self.mesh is not None:
-            state = jax.device_put(state, self._state_shardings(state))
+            state = jax.device_put(state, self._state_shardings(state),
+                                   donate=True)
         return state
 
     def _donor_tails(self, donor: dict) -> dict:
@@ -843,6 +839,14 @@ class ServeEngine:
     # -- the serving loop -----------------------------------------------------
 
     def run(self, requests: list[Request]) -> dict:
+        if self.mesh is None:
+            return self._run(requests)
+        # the steps trace under the mesh, so the library's Pallas kernels
+        # run per shard (the TPU compiler cannot partition them itself)
+        with jax.set_mesh(self.mesh):
+            return self._run(requests)
+
+    def _run(self, requests: list[Request]) -> dict:
         rids = [r.rid for r in requests]
         if len(set(rids)) != len(rids):
             raise ValueError("duplicate request rids (outputs and metrics "

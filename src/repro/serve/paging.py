@@ -72,38 +72,18 @@ from repro.kernels.paged import ref as _pref
 
 from .slots import PageAllocator, PagesExhausted, SlotPages
 
-DEFAULT_PAGE_SIZE = 64
-
-
-def upd_page_defaults() -> dict:
-    """The ``serve:`` block declared on the cache_page_read primitive:
-    {"page_size": int, "page_sizes": [int, ...]}. Falls back to module
-    defaults if the corpus (or the block) is missing."""
-    try:
-        from repro.core import load_corpus
-
-        blk = dict(load_corpus().primitives["cache_page_read"].extra["serve"])
-        return {"page_size": int(blk["page_size"]),
-                "page_sizes": tuple(int(p) for p in blk["page_sizes"])}
-    except Exception:
-        return {"page_size": DEFAULT_PAGE_SIZE,
-                "page_sizes": (DEFAULT_PAGE_SIZE,)}
-
-
 def selected_page_size() -> int:
     """Page size of the generated library's SELECTED cache_page_read
     definition (bench winner per hardware key, or the flag heuristic's
     first candidate). Probed, not parsed: the definition's page size is
     exactly the number of rows it gathers per table entry, so the library
-    itself is the source of truth."""
-    try:
-        from repro.tsl_api import ops
+    itself is the source of truth. A library without the primitive, or
+    one whose definition fails, raises: the pool geometry is never guessed."""
+    from repro.tsl_api import ops
 
-        out = ops.cache_page_read(jnp.zeros((1024, 1), jnp.float32),
-                                  jnp.zeros((1,), jnp.int32))
-        return int(out.shape[0])
-    except Exception:
-        return upd_page_defaults()["page_size"]
+    out = ops.cache_page_read(jnp.zeros((1024, 1), jnp.float32),
+                              jnp.zeros((1,), jnp.int32))
+    return int(out.shape[0])
 
 
 def prefix_key(*, arch: str, page_size: int, int8: bool, seed: int,

@@ -95,8 +95,10 @@ def init_train_state(model: Model, opt_cfg: OptConfig, key, *, mesh=None):
         from repro.dist import sharding as dist_sharding
 
         shardings = dist_sharding.param_shardings(mesh, params)
-        params = jax.device_put(params, shardings)
+        # donate: no unsharded copy stays on the default device
+        params = jax.device_put(params, shardings, donate=True)
         if opt_cfg.moment_dtype != "int8":
-            opt = {**opt, "m": jax.device_put(opt["m"], shardings),
-                   "v": jax.device_put(opt["v"], shardings)}
+            opt = {**opt,
+                   "m": jax.device_put(opt["m"], shardings, donate=True),
+                   "v": jax.device_put(opt["v"], shardings, donate=True)}
     return {"params": params, "opt": opt}

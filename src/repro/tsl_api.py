@@ -17,6 +17,8 @@ import os
 from pathlib import Path
 from types import ModuleType
 
+import jax
+
 from repro.core import generate_all, load_library
 
 _lib: ModuleType | None = None
@@ -26,6 +28,18 @@ def lib(force: bool = False) -> ModuleType:
     global _lib
     if _lib is None or force:
         _lib = load_library(os.environ.get("REPRO_TSL_TARGET", "auto"))
+    return _lib
+
+
+def use(target: str, *, force: bool = False,
+        build_root: Path | None = None) -> ModuleType:
+    """Route every later primitive call through the library generated for
+    ``target`` (``force`` regenerates it from the UPD, under ``build_root``
+    if given). JAX's trace caches are dropped too, so no function traced
+    against the previous library is reused."""
+    global _lib
+    _lib = load_library(target, force=force, build_root=build_root)
+    jax.clear_caches()
     return _lib
 
 

@@ -199,6 +199,44 @@ def test_bench_selection_persists_winners(tmp_path):
     assert list((tmp_path / "bench").glob("*.json")) == benches
 
 
+@pytest.mark.parametrize("live", [True, False], ids=["live", "emulated"])
+def test_failed_bench_candidate_is_an_error_on_the_live_target(
+        tmp_path, monkeypatch, live):
+    """On the live device's own target a candidate that fails is a
+    generation error; on an emulated dialect it ranks last with a warning."""
+    from repro.core import benchgen, hwprobe
+    from repro.core.pipeline import GenerationError
+
+    monkeypatch.setattr(hwprobe, "live_target",
+                        lambda: "pallas_interpret" if live else "cpu_xla")
+
+    def refused(fn, args, n_iter):
+        raise RuntimeError("refused by the compiler")
+
+    monkeypatch.setattr(benchgen, "_time_candidate", refused)
+    cfg = GenConfig(target="pallas_interpret", only=("hadd",),
+                    use_bench_selection=True)
+    if live:
+        with pytest.raises(GenerationError, match="refused by the compiler"):
+            generate_library(cfg, tmp_path, force=True)
+    else:
+        _, res = generate_library(cfg, tmp_path, force=True)
+        assert any("refused by the compiler" in w for w in res.warnings)
+
+
+def test_live_target_is_runnable_for_bench(monkeypatch):
+    """bench treats the probed device's target as runnable even though its
+    UPD document says it does not run on the host."""
+    from repro.core import hwprobe
+    from repro.core.corpus import load_corpus
+
+    targets = load_corpus().targets
+    assert not hwprobe.runnable(targets["tpu_v5e"])
+    monkeypatch.setattr(hwprobe, "live_target", lambda: "tpu_v5e")
+    assert hwprobe.runnable(targets["tpu_v5e"])
+    assert not hwprobe.runnable(targets["pallas_tpu"])
+
+
 def test_cli_generate_and_cache_roundtrip(tmp_path, capsys):
     from repro.core.cli import main
 

@@ -97,7 +97,7 @@ def test_flash_attention_vjp_standalone_entry():
 
 def test_fwd_residuals_are_linear_in_sequence():
     """The residuals saved by _fa_fwd are O(Sq) per head: the three inputs,
-    the output, and a (B, H, Sq) logsumexp — no S×S tensor (the oracle-VJP
+    the output, and a (B, H, Sq, 1) logsumexp — no S×S tensor (the oracle-VJP
     fallback this kernel replaced materialized exp-scores of (Sq, Sk))."""
     from repro.kernels.flash_attention import ops
 
@@ -106,7 +106,7 @@ def test_fwd_residuals_are_linear_in_sequence():
     k, v = _arr((b, h, sk, d)), _arr((b, h, sk, d))
     out, res = ops._fa_fwd(True, None, sk, 32, 64, True, q, k, v)
     assert out.shape == q.shape
-    expected = {q.shape, k.shape, (b, h, sq), }
+    expected = {q.shape, k.shape, (b, h, sq, 1)}
     for leaf in res:
         assert tuple(leaf.shape[-2:]) != (sq, sk), \
             f"S×S residual materialized: {leaf.shape}"
@@ -134,7 +134,9 @@ def test_fwd_logsumexp_residual_values():
     sm = np.where(mask, sm, -np.inf)
     want = np.log(np.exp(sm - sm.max(-1, keepdims=True)).sum(-1)) \
         + sm.max(-1, keepdims=True)[..., 0]
-    np.testing.assert_allclose(np.asarray(lse), want, rtol=1e-5, atol=1e-5)
+    assert lse.shape == (b, h, s, 1)
+    np.testing.assert_allclose(np.asarray(lse)[..., 0], want, rtol=1e-5,
+                               atol=1e-5)
 
 
 def test_generated_tsl_trains_through_pallas_backward():

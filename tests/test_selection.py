@@ -1,6 +1,7 @@
 """Selection-heuristic tests incl. hypothesis property tests (paper §3.2 ②)."""
 
 import hypothesis.strategies as st
+import pytest
 from hypothesis import given, settings
 
 from repro.core import GenConfig
@@ -109,3 +110,22 @@ def test_cherry_pick_closes_over_test_deps(lib_cpu):
     # but unrelated primitives are absent (slim)
     assert "flash_attention" not in prims
     assert "wkv6_scan" not in prims
+
+
+@pytest.mark.parametrize("kind,want", [("TPU v5 lite", "tpu_v5e"),
+                                       ("TPU v5", None), ("TPU v4", None)])
+def test_live_target_maps_exact_tpu_kinds(monkeypatch, kind, want):
+    """auto resolves a TPU by its exact device kind; a kind without a UPD
+    target is an error, never a library tuned for another chip."""
+    import types
+
+    from repro.core import hwprobe
+
+    monkeypatch.setattr(hwprobe.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(hwprobe.jax, "devices",
+                        lambda: [types.SimpleNamespace(device_kind=kind)])
+    if want is None:
+        with pytest.raises(RuntimeError, match="no UPD target"):
+            hwprobe.live_target()
+    else:
+        assert hwprobe.live_target() == want

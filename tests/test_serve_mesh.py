@@ -184,7 +184,7 @@ def test_mesh_train_step_runs_sharded():
     state = init_train_state(model, opt_cfg, key, mesh=mesh)
     step = jax.jit(make_train_step(model, opt_cfg, mesh=mesh),
                    donate_argnums=(0,))
-    with mesh:
+    with jax.set_mesh(mesh):
         new_state, metrics = step(state, batch)
     assert np.allclose(float(metrics["loss"]), float(solo[1]["loss"]),
                        rtol=1e-5)

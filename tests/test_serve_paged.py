@@ -463,3 +463,26 @@ def test_prefix_key_is_content_addressed():
     assert k != prefix_key(**{**base, "tokens": [1, 2, 4]})
     assert k != prefix_key(**{**base, "int8": True})
     assert k != prefix_key(**{**base, "seed": 1})
+
+
+@pytest.mark.parametrize("fault,error", [("missing", AttributeError),
+                                         ("raises", RuntimeError)])
+def test_selected_page_size_propagates_library_faults(monkeypatch, fault,
+                                                      error):
+    """The pool geometry comes from the generated library or nowhere: a
+    library without cache_page_read, or whose definition raises, is an
+    error, never a default page size."""
+    import types
+
+    from repro import tsl_api
+    from repro.serve import selected_page_size
+
+    def refused(*args, **kwargs):
+        raise RuntimeError("kernel refused")
+
+    ops = types.SimpleNamespace()
+    if fault == "raises":
+        ops.cache_page_read = refused
+    monkeypatch.setattr(tsl_api, "_lib", types.SimpleNamespace(ops=ops))
+    with pytest.raises(error):
+        selected_page_size()
