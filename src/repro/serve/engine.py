@@ -20,9 +20,7 @@ whole-prompt prefill.
 
 Requests arrive asynchronously: ``submit()`` (or a preset ``arrival_s`` on
 the request) makes a request visible to admission only once the engine clock
-reaches its arrival — TTFT and SLA accounting are measured from that arrival,
-and shared-step wall time is attributed proportionally to prefill vs decode
-tokens so a neighbour's chunk work never inflates a request's decode-t/s.
+reaches its arrival — TTFT and SLA accounting are measured from that arrival.
 
 Speculative decoding (``speculation=SpeculationConfig(...)``) replaces the
 per-step decode with a draft/verify loop when the UPD cost channel says it
@@ -35,13 +33,18 @@ and k = 0 runs the ORIGINAL decode path verbatim — same jitted function,
 same sampler, same key draws — so disabled/unprofitable speculation is
 token-for-token identical to the plain engine.
 
-Metrics per request: TTFT, prefill_s/decode_s attribution, decode tokens/s
-(counting ONLY target-emitted tokens — accepted + corrected, never rejected
-drafts), end-to-end latency, SLA hit, bucket; per engine run: real-token
-throughput (padded/idle slots never counted), steady-state padded-slot steps
-(0 == true continuous batching), TTFT percentiles split by bucket, slot-reuse
-counts, the per-step log (chunks run / tokens decoded / emitted), the
-admission log, every refusal with its cost-model reason, and — with
+Metrics per request, measured on the engine clock: TTFT split into its queue
+wait (arrival -> reservation) and prefill (reservation -> first token), the
+time parked with no lane, the host time of every emitted token, decode
+tokens/s over the time the request held a lane (counting ONLY target-emitted
+tokens — accepted + corrected, never rejected drafts), end-to-end latency,
+SLA hit, bucket; per engine run: real-token throughput (padded/idle slots
+never counted), steady-state padded-slot steps (0 == true continuous
+batching), decode steps and the slots they ran, host seconds and counts per
+loop phase (``phases``: each phase is also a ``serve.<phase>`` span in a
+profiler trace, see ``phases.py``), TTFT percentiles split by bucket,
+slot-reuse counts, the per-step log (chunks run / tokens decoded / emitted),
+the admission log, every refusal with its cost-model reason, and — with
 speculation on — the ``spec`` block (accepted rate, mean accepted span,
 steps per emitted token, split by bucket).
 """
@@ -60,11 +63,24 @@ from repro.dist import sharding as dist_sharding
 from repro.nn.model import build_model
 
 from .paging import PagedConfig, PagedKVStore, prefix_key, selected_page_size
+from .phases import Phases
 from .scheduler import (BucketPolicy, CostModelAdmission, PagedAdmission,
                         Refusal, Request, Scheduler)
 from .slots import PagesExhausted, assert_span_fits, validate_donor
 from .spec import (SpeculationConfig, SpeculationPolicy, accept_span,
                    build_drafter, upd_verify_defaults)
+
+
+def _named(name: str, fn):
+    """``fn`` under a fixed name, so that its compiled program is
+    ``jit_<name>`` in a device trace whatever ``fn`` is (a lambda, a bound
+    method, a mesh wrapper)."""
+
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 @dataclass(frozen=True)
@@ -94,7 +110,6 @@ class _PrefillTask:
     chunk_idx: int = 0
     fill: int = 0               # REAL rows in the donor's cache (incl. prefix)
     first_logits: np.ndarray | None = None   # logits at the last real row
-    prefill_s: float = 0.0
     # paged mode (slot == -1: no lane is reserved; the request activates
     # into a free lane at completion or parks resident in pages)
     share_key: str | None = None    # prefix-store content address
@@ -336,14 +351,19 @@ class ServeEngine:
             self._spec_policy = SpeculationPolicy(
                 batch, self._k_max, pricing, speculation,
                 drafter_cost_s=self._drafter.cost_per_token_s())
-            self._verify = jax.jit(_ls(self.model.verify_step),
-                                   donate_argnums=(1,))
+            self._verify = jax.jit(
+                _named("serve_verify", _ls(self.model.verify_step)),
+                donate_argnums=(1,))
             if self.model.verify_commit is not None:
-                self._commit = jax.jit(_st(self.model.verify_commit),
-                                       donate_argnums=(1,))
+                self._commit = jax.jit(
+                    _named("serve_commit", _st(self.model.verify_commit)),
+                    donate_argnums=(1,))
         # donate the incoming state: it is dead after every call, and without
-        # donation each step/insert/reset copies the full multi-layer cache
-        self._decode = jax.jit(_ls(self.model.decode_step), donate_argnums=(1,))
+        # donation each step/insert/reset copies the full multi-layer cache.
+        # Every program has a fixed name (``jit_serve_*`` in a device trace).
+        self._decode = jax.jit(_named("serve_decode",
+                                      _ls(self.model.decode_step)),
+                               donate_argnums=(1,))
         # fused paged steps: the tail state AND the pool dict are donated —
         # the pools are updated in place on device and re-adopted by the
         # store after every call (set_device_pools)
@@ -351,18 +371,28 @@ class ServeEngine:
         self._verify_paged = None
         self._commit_paged = None
         if self._fused:
-            self._decode_paged = jax.jit(_lsp(self.model.decode_step_paged),
-                                         donate_argnums=(1, 2))
+            self._decode_paged = jax.jit(
+                _named("serve_decode_paged",
+                       _lsp(self.model.decode_step_paged)),
+                donate_argnums=(1, 2))
             if speculation is not None:
-                self._verify_paged = jax.jit(_lsp(self.model.verify_step_paged),
-                                             donate_argnums=(1, 2))
+                self._verify_paged = jax.jit(
+                    _named("serve_verify_paged",
+                           _lsp(self.model.verify_step_paged)),
+                    donate_argnums=(1, 2))
                 if self.model.verify_commit_paged is not None:
                     self._commit_paged = jax.jit(
-                        _sp(self.model.verify_commit_paged),
+                        _named("serve_commit_paged",
+                               _sp(self.model.verify_commit_paged)),
                         donate_argnums=(1, 2))
-        self._insert = jax.jit(_st(self.model.insert_slot), donate_argnums=(0,))
-        self._reset = jax.jit(_st(self.model.reset_slot), donate_argnums=(0,))
-        self._chunk = jax.jit(self._chunk_fn, donate_argnums=(1,))
+        self._insert = jax.jit(_named("serve_insert",
+                                      _st(self.model.insert_slot)),
+                               donate_argnums=(0,))
+        self._reset = jax.jit(_named("serve_reset",
+                                     _st(self.model.reset_slot)),
+                              donate_argnums=(0,))
+        self._chunk = jax.jit(_named("serve_chunk", self._chunk_fn),
+                              donate_argnums=(1,))
         self._sample = self._build_sampler()
         self._key = jax.random.PRNGKey(seed + 1)
         self._inbox: list[Request] = []
@@ -407,7 +437,7 @@ class ServeEngine:
                 (logits.shape[0],) + (1,) * (logits.ndim - 2)) > 0
             return jnp.where(use_draw, drawn, greedy)
 
-        return jax.jit(sample)
+        return jax.jit(_named("serve_sample", sample))
 
     def _slot_temperature(self, req: Request) -> float:
         return self.sampling.temperature if req.temperature is None \
@@ -627,10 +657,10 @@ class ServeEngine:
             elif share_key is not None:
                 task.publish = True
                 self._inflight_keys[share_key] = req.rid
-            sched.reserve_unplaced(req, step)
+            sched.reserve_unplaced(req, step, now())
             tasks.append(task)
 
-    def _activate_parked(self, sched: Scheduler, state, pending_host,
+    def _activate_parked(self, sched: Scheduler, state, now, pending_host,
                          pos_host, temps_host, histories):
         """Drain parked (resident, lane-less) requests into free lanes,
         FIFO: gather the request's pages back into a fresh donor
@@ -656,7 +686,7 @@ class ServeEngine:
                 self._lane_activations += 1
             validate_donor(state, donor, self.model.state_batch_axes(state))
             state = self._insert(state, donor, slot)
-            sched.place_parked(rid, slot)
+            sched.place_parked(rid, slot, now())
             temps_host[slot] = info["temp"]
             pending_host[slot] = info["pending"]
             pos_host[slot] = info["fill"]
@@ -669,7 +699,8 @@ class ServeEngine:
                 self._drafter.on_graft(rid, slot, histories[slot])
         return state
 
-    def _preempt_slot(self, slot: int, sched: Scheduler, state, histories):
+    def _preempt_slot(self, slot: int, sched: Scheduler, state, histories,
+                      now_s: float):
         """Page exhaustion: evict the latest-activated decoding request from
         its lane, free its pages, and requeue a CONTINUATION at the queue
         head — prompt = everything the model has consumed, resume_token =
@@ -689,8 +720,11 @@ class ServeEngine:
             "gen_len": m.gen_len,
             "ttft_s": m.ttft_s,
             "tokens_out": m.tokens_out,
+            "queue_s": m.queue_s,
             "prefill_s": m.prefill_s,
-            "decode_s": m.decode_s,
+            "park_s": m.park_s,
+            "token_s": m.token_s,
+            "requeued_s": now_s,    # its second queue wait starts here
             "preemptions": m.preemptions + 1,
             "spec_proposed": m.spec_proposed,
             "spec_accepted": m.spec_accepted,
@@ -711,7 +745,7 @@ class ServeEngine:
         return state
 
     def _grow_or_preempt(self, active, k_vec, sched, state, pos_host,
-                         histories):
+                         histories, now):
         """Before phase 2: every decoding slot's pages must cover the rows
         this step may commit (pos + depth + 1, capped at max_len — verify
         scratch rows beyond max_len only ever hold rejected drafts and are
@@ -735,7 +769,7 @@ class ServeEngine:
                     victim = max(victims,
                                  key=lambda s: self._act_stamp.get(s, -1))
                     state = self._preempt_slot(victim, sched, state,
-                                               histories)
+                                               histories, now())
                     active.remove(victim)
                     k_vec[victim] = 0
                     if victim == slot:
@@ -764,9 +798,13 @@ class ServeEngine:
         m = sched.unplaced_metrics(rid)
         stash = self._resumed.pop(rid, None)
         if stash is not None:
+            # a continuation: its wait since the preemption joins the queue
+            # wait its first admission recorded
+            reserved_s = req.arrival_s + m.queue_s
+            waited = reserved_s - stash.pop("requeued_s")
             for name, val in stash.items():
                 setattr(m, name, val)
-        m.prefill_s += task.prefill_s
+            m.queue_s += max(waited, 0.0)
         temp = self._slot_temperature(req)
         gen_inc = 0
         if req.resume_token is not None:
@@ -777,7 +815,9 @@ class ServeEngine:
                 jnp.asarray([temp], np.float32)))[0])
             outputs[rid] = [first]
             gen_inc = 1
-            sched.first_token_unplaced(rid, now())
+        ready_s = now()         # the first token (or the resumed one) is here
+        if gen_inc:
+            sched.first_token_unplaced(rid, ready_s)
         history = [int(t) for t in np.asarray(req.tokens)] + [first]
         if m.tokens_out >= m.gen_len:
             # gen_len == 1: finished without ever taking a lane
@@ -792,7 +832,7 @@ class ServeEngine:
             validate_donor(state, donor,
                            self.model.state_batch_axes(state))
             state = self._insert(state, donor, slot)
-            sched.place_parked(rid, slot)
+            sched.place_parked(rid, slot, ready_s)
             temps_host[slot] = temp
             pending_host[slot] = first
             pos_host[slot] = task.fill
@@ -806,8 +846,94 @@ class ServeEngine:
         else:
             self._parked[rid] = {"pending": first, "fill": task.fill,
                                  "temp": temp, "history": history}
+            sched.park(rid, ready_s)
             self._store.unpin(rid)      # parked: cold, host-spillable
         return state, gen_inc
+
+    def _drain_inbox(self, sched: Scheduler, seen_rids: set, now) -> None:
+        """Move submitted requests into the scheduler. Pop-drain (never
+        iterate-then-clear): a concurrent submit() landing between the two
+        would be silently dropped."""
+        while self._inbox:
+            r = self._inbox.pop(0)
+            # streamed requests get the same validation as run()'s list,
+            # but as recorded refusals — one bad submit must not kill
+            # the loop for everyone already being served
+            if r.rid in seen_rids:
+                sched.refused.append(Refusal(
+                    r.rid, "invalid: duplicate rid in this run"))
+                continue
+            if r.gen_len < 1:
+                sched.refused.append(Refusal(
+                    r.rid, "invalid: gen_len must be >= 1 (the first "
+                           "token always comes from prefill)"))
+                continue
+            seen_rids.add(r.rid)
+            sched.submit(r, now())
+
+    def _reserve_lanes(self, sched: Scheduler, tasks: list, now, step: int):
+        """Lane reservation: every free slot starts a chunk schedule."""
+        chunk = self.policy.chunk
+        while True:
+            free = sched.free_slots()
+            if not free:
+                break
+            req = sched.next_admissible(now())
+            if req is None:
+                break
+            bucket = req.bucket or self.policy.assign(req.prompt_len)
+            if not bucket:
+                # admission off + prompt beyond the largest bucket:
+                # still cover the whole prompt in whole chunks (the
+                # max_len overrun guard stays the only hard stop)
+                bucket = BucketPolicy.round_up(req.prompt_len, chunk)
+            req.bucket = bucket
+            padded = np.zeros((1, bucket), np.int64)
+            padded[0, :req.prompt_len] = np.asarray(req.tokens, np.int64)
+            tasks.append(_PrefillTask(
+                req=req, slot=free[0], padded=padded,
+                n_chunks=bucket // chunk,
+                donor=self._new_donor()))
+            sched.reserve(free[0], req, step, now())
+
+    def _run_chunk(self, task: _PrefillTask) -> None:
+        """Advance one in-flight prefill by one chunk, up to the host sync
+        of its logits."""
+        chunk = self.policy.chunk
+        c0 = task.chunk_idx * chunk
+        seg = jnp.asarray(task.padded[:, c0:c0 + chunk], jnp.int32)
+        n_real = max(0, min(task.req.prompt_len - c0, chunk))
+        embeds = self._first_chunk_embeds(task.req) \
+            if task.chunk_idx == 0 else None
+        writes = chunk + (self._prefix if task.chunk_idx == 0 else 0)
+        if task.fill + writes > self.max_len:
+            raise RuntimeError(
+                f"prefill chunk for {task.req.rid!r} would overrun "
+                f"max_len={self.max_len} (admission off?)")
+        last, task.donor = self._chunk(
+            self.params, task.donor, seg,
+            jnp.int32(task.fill), jnp.int32(n_real), embeds)
+        if self._drafter is not None:
+            # a draft-model drafter mirrors the chunk schedule into
+            # its own donor (no-op for the n-gram drafter)
+            self._drafter.on_chunk(task.req.rid,
+                                   task.padded[:, c0:c0 + chunk],
+                                   n_real)
+        task.chunk_idx += 1
+        if task.chunk_idx == 1:
+            task.fill += self._prefix       # vlm vision rows
+        if n_real:
+            task.fill += n_real
+            task.first_logits = np.asarray(last)    # syncs the chunk
+        if (task.publish and task.boundary_tail is None
+                and self._store is not None
+                and self._store.tail_leaves
+                and task.fill >= task.share_rows):
+            # recurrent-tail families: the prefix entry must restore
+            # the state AT the boundary, so snapshot it the moment
+            # the fill crosses (boundary is chunk-aligned — the
+            # crossing is exact)
+            task.boundary_tail = self._store.snapshot_tail(task.donor)
 
     def jit_cache_sizes(self) -> dict:
         """Compiled-entry counts of the engine's jitted device functions —
@@ -890,320 +1016,280 @@ class ServeEngine:
         spec_slot_rounds = 0        # (slot, round) pairs that speculated
         chunk = self.policy.chunk
 
+        phases = Phases()
         seen_rids = set(rids)
         while sched.has_work() or tasks or self._inbox:
-            t_step0 = time.perf_counter()
-            # pop-drain (never iterate-then-clear): a concurrent submit()
-            # landing between the two would be silently dropped
-            while self._inbox:
-                r = self._inbox.pop(0)
-                # streamed requests get the same validation as run()'s list,
-                # but as recorded refusals — one bad submit must not kill
-                # the loop for everyone already being served
-                if r.rid in seen_rids:
-                    sched.refused.append(Refusal(
-                        r.rid, "invalid: duplicate rid in this run"))
-                    continue
-                if r.gen_len < 1:
-                    sched.refused.append(Refusal(
-                        r.rid, "invalid: gen_len must be >= 1 (the first "
-                               "token always comes from prefill)"))
-                    continue
-                seen_rids.add(r.rid)
-                sched.submit(r, now())
-            sched.release(now())
-
-            # -- reservation: every free slot starts a chunk schedule --------
-            # (paged mode: activation + admission are PAGE decisions — drain
-            # parked requests into freed lanes, then admit lane-less)
-            if self._store is not None:
-                state = self._activate_parked(sched, state, pending_host,
-                                              pos_host, temps_host,
-                                              histories)
-                self._reserve_paged(sched, tasks, now, step)
-            else:
-                while True:
-                    free = sched.free_slots()
-                    if not free:
-                        break
-                    req = sched.next_admissible(now())
-                    if req is None:
-                        break
-                    bucket = req.bucket or self.policy.assign(req.prompt_len)
-                    if not bucket:
-                        # admission off + prompt beyond the largest bucket:
-                        # still cover the whole prompt in whole chunks (the
-                        # max_len overrun guard stays the only hard stop)
-                        bucket = BucketPolicy.round_up(req.prompt_len, chunk)
-                    req.bucket = bucket
-                    padded = np.zeros((1, bucket), np.int64)
-                    padded[0, :req.prompt_len] = np.asarray(req.tokens,
-                                                            np.int64)
-                    tasks.append(_PrefillTask(
-                        req=req, slot=free[0], padded=padded,
-                        n_chunks=bucket // chunk,
-                        donor=self._new_donor()))
-                    sched.reserve(free[0], req, step)
-
-            # -- unified step, phase 1: one chunk per in-flight prefill ------
-            ran: list[_PrefillTask] = []
-            for task in tasks:
-                c0 = task.chunk_idx * chunk
-                seg = jnp.asarray(task.padded[:, c0:c0 + chunk], jnp.int32)
-                n_real = max(0, min(task.req.prompt_len - c0, chunk))
-                embeds = self._first_chunk_embeds(task.req) \
-                    if task.chunk_idx == 0 else None
-                writes = chunk + (self._prefix if task.chunk_idx == 0 else 0)
-                if task.fill + writes > self.max_len:
-                    raise RuntimeError(
-                        f"prefill chunk for {task.req.rid!r} would overrun "
-                        f"max_len={self.max_len} (admission off?)")
-                last, task.donor = self._chunk(
-                    self.params, task.donor, seg,
-                    jnp.int32(task.fill), jnp.int32(n_real), embeds)
-                if self._drafter is not None:
-                    # a draft-model drafter mirrors the chunk schedule into
-                    # its own donor (no-op for the n-gram drafter)
-                    self._drafter.on_chunk(task.req.rid,
-                                           task.padded[:, c0:c0 + chunk],
-                                           n_real)
-                task.chunk_idx += 1
-                ran.append(task)
-                if task.chunk_idx == 1:
-                    task.fill += self._prefix       # vlm vision rows
-                if n_real:
-                    task.fill += n_real
-                    task.first_logits = np.asarray(last)    # syncs the chunk
-                if (task.publish and task.boundary_tail is None
-                        and self._store is not None
-                        and self._store.tail_leaves
-                        and task.fill >= task.share_rows):
-                    # recurrent-tail families: the prefix entry must restore
-                    # the state AT the boundary, so snapshot it the moment
-                    # the fill crosses (boundary is chunk-aligned — the
-                    # crossing is exact)
-                    task.boundary_tail = self._store.snapshot_tail(task.donor)
-            chunk_tokens = len(ran) * chunk
-            prefill_tokens_total += chunk_tokens
-
-            active = sched.active_slots()
-            if sched.queue and self._store is None:
-                # released queue still has work: every free, unreserved slot
-                # this step is waste. With per-step admission this is 0 by
-                # construction — the counter is a tripwire so any future
-                # scheduling policy that delays admission surfaces its cost
-                # here instead of silently regressing
-                padded_steady += self.batch - len(active) - len(tasks)
-
-            # -- phase 2: one decode OR verify step over every occupied slot -
-            emitted_this_step = 0
-            # per-slot speculation depth, priced per step: clipped to the
-            # slot's remaining generation budget, 0 when the cost channel
-            # says drafting doesn't pay (or speculation is off)
-            k_vec = np.zeros(self.batch, np.int64)
-            if active and self._spec_policy is not None:
-                for slot in active:
-                    s_ = sched.slots[slot]
-                    remaining = s_.request.gen_len - s_.metrics.tokens_out
-                    k_vec[slot] = self._spec_policy.depth(
-                        slot, int(pos_host[slot]), remaining)
-            if active and self._store is not None:
-                # page growth for the rows this step commits; exhaustion
-                # preempts LIFO back to the queue head
-                active, state = self._grow_or_preempt(
-                    active, k_vec, sched, state, pos_host, histories)
-            if active:
-                if int(pos_host[active].max()) >= self.max_len:
-                    # reachable only with admission=False (admission's
-                    # over_budget check forbids it): fail loudly rather than
-                    # silently clobbering the last cache row
-                    raise RuntimeError(
-                        f"active slot position {int(pos_host[active].max())} "
-                        f"overran max_len={self.max_len}")
-                K = int(k_vec.max())
-                pos_vec = jnp.asarray(pos_host, jnp.int32)
-                temps = jnp.asarray(temps_host)
-                tables = pools = None
-                if self._fused:
-                    # steady-state fused path: this step reads/writes the
-                    # pools THROUGH the block table — no page->lane gather
-                    tables = self._build_tables(sched, active)
-                    pools = self._sharded_device_pools()
-                if K == 0:
-                    # degraded path: EXACTLY today's decode step — same jitted
-                    # fn, same sampler call, same key draw — so k=0
-                    # speculation is token-for-token identical to PR 5 decode
-                    tokens = jnp.asarray(pending_host[:, None], jnp.int32)
-                    if self._fused:
-                        logits, state, pools = self._decode_paged(
-                            self.params, state, pools, tables, tokens,
-                            pos_vec)
-                        self._store.set_device_pools(pools)
-                    else:
-                        logits, state = self._decode(self.params, state,
-                                                     tokens, pos_vec)
-                    toks = np.asarray(self._sample(logits, self._next_key(),
-                                                   temps))
-                    decode_steps += 1
-                    for slot in active:
-                        rid = sched.slots[slot].request.rid
-                        sched.step_done(slot)
-                        pos_host[slot] += 1
-                        pending_host[slot] = int(toks[slot])
-                        outputs[rid].append(int(toks[slot]))
-                        if slot in histories:
-                            histories[slot].append(int(toks[slot]))
-                        generated += 1
-                        emitted_this_step += 1
-                else:
-                    # speculative round: draft -> ONE ragged batched verify ->
-                    # accept longest prefix + corrected token -> commit
-                    drafts = self._drafter.propose(active, histories, k_vec,
-                                                   self.batch, K)
-                    span_np = np.concatenate(
-                        [pending_host[:, None], drafts], axis=1)
-                    # the whole table takes the slab write (inactive rows
-                    # included), so the guard covers every slot's position
-                    assert_span_fits(pos_host, K + 1, self._state_len)
-                    span = jnp.asarray(span_np, jnp.int32)
-                    if self._fused:
-                        logits, state, pools = self._verify_paged(
-                            self.params, state, pools, tables, span, pos_vec)
-                    else:
-                        logits, state = self._verify(self.params, state, span,
-                                                     pos_vec)
-                    # sample the target token at EVERY span row (per-slot
-                    # temperature); row j validates draft j+1, row m yields
-                    # the corrected token for a slot accepting m drafts
-                    tgt = np.asarray(self._sample(logits, self._next_key(),
-                                                  temps))
-                    m_vec = accept_span(drafts, tgt, k_vec)
-                    n_commit = np.zeros(self.batch, np.int64)
-                    for slot in active:
-                        n_commit[slot] = m_vec[slot] + 1
-                    if self._fused:
-                        if self._commit_paged is not None:
-                            state, pools = self._commit_paged(
-                                self.params, state, pools, tables, span,
-                                pos_vec, jnp.asarray(n_commit, jnp.int32))
-                        self._store.set_device_pools(pools)
-                    elif self._commit is not None:
-                        # recurrent/hybrid: replay the accepted prefix of the
-                        # span through the chunked-prefill path (per-slot
-                        # n_commit real rows; 0 == exact identity, so
-                        # rejected or inactive slots are never perturbed)
-                        state = self._commit(
-                            self.params, state, span, pos_vec,
-                            jnp.asarray(n_commit, jnp.int32))
-                    verify_steps += 1
-                    for slot in active:
-                        rid = sched.slots[slot].request.rid
-                        m = int(m_vec[slot])
-                        emit = [int(t) for t in drafts[slot, :m]]
-                        emit.append(int(tgt[slot, m]))
-                        sched.step_done(slot, n=len(emit))
-                        if k_vec[slot] > 0:
-                            sched.spec_round(slot, proposed=int(k_vec[slot]),
-                                             accepted=m)
-                            self._spec_policy.update(slot, int(k_vec[slot]),
-                                                     m)
-                            spec_proposed_total += int(k_vec[slot])
-                            spec_accepted_total += m
-                            spec_slot_rounds += 1
-                        pos_host[slot] += len(emit)
-                        pending_host[slot] = emit[-1]
-                        outputs[rid].extend(emit)
-                        if slot in histories:
-                            histories[slot].extend(emit)
-                        if self._drafter is not None:
-                            self._drafter.on_commit(slot, m)
-                        generated += len(emit)
-                        emitted_this_step += len(emit)
-
-            if active and self.mesh is not None:
-                # steady-state audit: the step must have returned state (and
-                # pools) in exactly the rule shardings it received them with
-                self._check_steady_sharding(
-                    state, self._store.device_pools() if self._fused else None)
-
-            # -- phase 3: shared-step time attribution (prefill vs decode) ---
-            decode_emitted += emitted_this_step
-            slot_steps += len(active)
-            t_step = time.perf_counter() - t_step0
-            pre_share, _ = sched.attribute_step_time(
-                t_step, chunk_tokens, active,
-                decode_tokens=emitted_this_step)
-            for task in ran:
-                task.prefill_s += pre_share / max(len(ran), 1)
-
-            if ran or active:
-                step_log.append({"step": step,
-                                 "prefill_rids": [t.req.rid for t in ran],
-                                 "chunks": len(ran),
-                                 "decoded": len(active),
-                                 "emitted": emitted_this_step})
-
-            # -- phase 4: completions (finished prefills + finished decodes) -
-            for task in list(tasks):
-                if task.chunk_idx < task.n_chunks:
-                    continue
-                if self._store is not None:
-                    state, gen_inc = self._complete_paged(
-                        task, sched, state, now, outputs, histories,
-                        pending_host, pos_host, temps_host)
-                    generated += gen_inc
-                    tasks.remove(task)
-                    continue
-                # prefill complete: graft the donor into its reserved slot,
-                # sample the first token, occupy
-                slot = task.slot
-                temps_host[slot] = self._slot_temperature(task.req)
-                first = int(np.asarray(self._sample(
-                    jnp.asarray(task.first_logits), self._next_key(),
-                    jnp.asarray(temps_host[slot:slot + 1])))[0])
-                validate_donor(state, task.donor,
-                               self.model.state_batch_axes(state))
-                state = self._insert(state, task.donor, slot)
-                sched.place(task.req, slot)
-                sched.add_prefill_time(slot, task.prefill_s)
-                sched.first_token(slot, now())
-                generated += 1
-                outputs[task.req.rid] = [first]
-                pending_host[slot] = first
-                pos_host[slot] = task.fill
-                # committed token history (prompt + emitted): the drafter's
-                # lookup corpus, reset on every slot reuse
-                histories[slot] = [int(t) for t in
-                                   np.asarray(task.req.tokens)] + [first]
-                if self._spec_policy is not None:
-                    self._spec_policy.reset(slot)
-                if self._drafter is not None:
-                    self._drafter.on_graft(task.req.rid, slot,
-                                           histories[slot])
-                tasks.remove(task)
-                if sched.slot_done(slot):           # gen_len == 1 edge case
-                    sched.finish(slot, now())
-                    state = self._reset(state, slot)
-                    if self._drafter is not None:
-                        self._drafter.on_finish(slot)
-            for slot in list(active):
-                if sched.slot_done(slot):
-                    rid_done = sched.slots[slot].request.rid
-                    sched.finish(slot, now())
-                    state = self._reset(state, slot)
+            nxt = sched.next_arrival_s()
+            if nxt is not None and nxt > now() and not (
+                    tasks or self._inbox or sched.queue or self._parked
+                    or sched.active_slots()):
+                # nothing to run until the next scheduled arrival
+                with phases.span("wait_arrival"):
+                    time.sleep(max(0.0, min(nxt - now(), 0.05)))
+                continue
+            with phases.span("step", step=step) as step_span:
+                with phases.span("admit"):
+                    self._drain_inbox(sched, seen_rids, now)
+                    sched.release(now())
+                    # -- reservation: every free slot starts a chunk
+                    # schedule (paged mode: activation + admission are PAGE
+                    # decisions — drain parked requests into freed lanes,
+                    # then admit lane-less)
                     if self._store is not None:
-                        self._store.free(rid_done)
-                        self._act_stamp.pop(slot, None)
-                    if self._drafter is not None:
-                        self._drafter.on_finish(slot)
+                        state = self._activate_parked(
+                            sched, state, now, pending_host, pos_host,
+                            temps_host, histories)
+                        self._reserve_paged(sched, tasks, now, step)
+                    else:
+                        self._reserve_lanes(sched, tasks, now, step)
 
+                # -- unified step, phase 1: one chunk per in-flight prefill --
+                ran: list[_PrefillTask] = []
+                for task in tasks:
+                    with phases.span("prefill_chunk", rid=task.req.rid,
+                                     chunk=task.chunk_idx):
+                        self._run_chunk(task)
+                    ran.append(task)
+                prefill_tokens_total += len(ran) * chunk
+
+                active = sched.active_slots()
+                if sched.queue and self._store is None:
+                    # released queue still has work: every free, unreserved
+                    # slot this step is waste. With per-step admission this
+                    # is 0 by construction — the counter is a tripwire so
+                    # any future scheduling policy that delays admission
+                    # surfaces its cost here instead of silently regressing
+                    padded_steady += self.batch - len(active) - len(tasks)
+
+                # -- phase 2: one decode OR verify step over every occupied
+                # slot
+                emitted_this_step = 0
+                if active:
+                    with phases.span("decode"):
+                        # per-slot speculation depth, priced per step:
+                        # clipped to the slot's remaining generation
+                        # budget, 0 when the cost channel says drafting
+                        # doesn't pay (or speculation is off)
+                        k_vec = np.zeros(self.batch, np.int64)
+                        if self._spec_policy is not None:
+                            for slot in active:
+                                s_ = sched.slots[slot]
+                                remaining = (s_.request.gen_len
+                                             - s_.metrics.tokens_out)
+                                k_vec[slot] = self._spec_policy.depth(
+                                    slot, int(pos_host[slot]), remaining)
+                        if self._store is not None:
+                            # page growth for the rows this step commits;
+                            # exhaustion preempts LIFO back to the queue
+                            # head
+                            active, state = self._grow_or_preempt(
+                                active, k_vec, sched, state, pos_host,
+                                histories, now)
+                        if active:
+                            if int(pos_host[active].max()) >= self.max_len:
+                                # reachable only with admission=False
+                                # (admission's over_budget check forbids
+                                # it): fail loudly rather than silently
+                                # clobbering the last cache row
+                                raise RuntimeError(
+                                    f"active slot position "
+                                    f"{int(pos_host[active].max())} overran "
+                                    f"max_len={self.max_len}")
+                            K = int(k_vec.max())
+                            pos_vec = jnp.asarray(pos_host, jnp.int32)
+                            temps = jnp.asarray(temps_host)
+                            tables = pools = None
+                            if self._fused:
+                                # steady-state fused path: this step
+                                # reads/writes the pools THROUGH the block
+                                # table — no page->lane gather
+                                tables = self._build_tables(sched, active)
+                                pools = self._sharded_device_pools()
+                            if K == 0:
+                                # degraded path: EXACTLY the plain decode
+                                # step — same jitted fn, same sampler call,
+                                # same key draw — so k=0 speculation is
+                                # token-for-token identical to plain decode
+                                tokens = jnp.asarray(pending_host[:, None],
+                                                     jnp.int32)
+                                if self._fused:
+                                    logits, state, pools = \
+                                        self._decode_paged(
+                                            self.params, state, pools,
+                                            tables, tokens, pos_vec)
+                                    self._store.set_device_pools(pools)
+                                else:
+                                    logits, state = self._decode(
+                                        self.params, state, tokens, pos_vec)
+                                toks = np.asarray(self._sample(
+                                    logits, self._next_key(), temps))
+                                t_tok = now()   # the step's tokens are here
+                                decode_steps += 1
+                                for slot in active:
+                                    rid = sched.slots[slot].request.rid
+                                    sched.step_done(slot, now_s=t_tok)
+                                    pos_host[slot] += 1
+                                    pending_host[slot] = int(toks[slot])
+                                    outputs[rid].append(int(toks[slot]))
+                                    if slot in histories:
+                                        histories[slot].append(
+                                            int(toks[slot]))
+                                    generated += 1
+                                    emitted_this_step += 1
+                            else:
+                                # speculative round: draft -> ONE ragged
+                                # batched verify -> accept longest prefix +
+                                # corrected token -> commit
+                                drafts = self._drafter.propose(
+                                    active, histories, k_vec, self.batch, K)
+                                span_np = np.concatenate(
+                                    [pending_host[:, None], drafts], axis=1)
+                                # the whole table takes the slab write
+                                # (inactive rows included), so the guard
+                                # covers every slot's position
+                                assert_span_fits(pos_host, K + 1,
+                                                 self._state_len)
+                                span = jnp.asarray(span_np, jnp.int32)
+                                if self._fused:
+                                    logits, state, pools = \
+                                        self._verify_paged(
+                                            self.params, state, pools,
+                                            tables, span, pos_vec)
+                                else:
+                                    logits, state = self._verify(
+                                        self.params, state, span, pos_vec)
+                                # sample the target token at EVERY span row
+                                # (per-slot temperature); row j validates
+                                # draft j+1, row m yields the corrected
+                                # token for a slot accepting m drafts
+                                tgt = np.asarray(self._sample(
+                                    logits, self._next_key(), temps))
+                                t_tok = now()   # the step's tokens are here
+                                m_vec = accept_span(drafts, tgt, k_vec)
+                                n_commit = np.zeros(self.batch, np.int64)
+                                for slot in active:
+                                    n_commit[slot] = m_vec[slot] + 1
+                                if self._fused:
+                                    if self._commit_paged is not None:
+                                        state, pools = self._commit_paged(
+                                            self.params, state, pools,
+                                            tables, span, pos_vec,
+                                            jnp.asarray(n_commit, jnp.int32))
+                                    self._store.set_device_pools(pools)
+                                elif self._commit is not None:
+                                    # recurrent/hybrid: replay the accepted
+                                    # prefix of the span through the
+                                    # chunked-prefill path (per-slot
+                                    # n_commit real rows; 0 == exact
+                                    # identity, so rejected or inactive
+                                    # slots are never perturbed)
+                                    state = self._commit(
+                                        self.params, state, span, pos_vec,
+                                        jnp.asarray(n_commit, jnp.int32))
+                                verify_steps += 1
+                                for slot in active:
+                                    rid = sched.slots[slot].request.rid
+                                    m = int(m_vec[slot])
+                                    emit = [int(t) for t in drafts[slot, :m]]
+                                    emit.append(int(tgt[slot, m]))
+                                    sched.step_done(slot, n=len(emit),
+                                                    now_s=t_tok)
+                                    if k_vec[slot] > 0:
+                                        sched.spec_round(
+                                            slot, proposed=int(k_vec[slot]),
+                                            accepted=m)
+                                        self._spec_policy.update(
+                                            slot, int(k_vec[slot]), m)
+                                        spec_proposed_total += int(
+                                            k_vec[slot])
+                                        spec_accepted_total += m
+                                        spec_slot_rounds += 1
+                                    pos_host[slot] += len(emit)
+                                    pending_host[slot] = emit[-1]
+                                    outputs[rid].extend(emit)
+                                    if slot in histories:
+                                        histories[slot].extend(emit)
+                                    if self._drafter is not None:
+                                        self._drafter.on_commit(slot, m)
+                                    generated += len(emit)
+                                    emitted_this_step += len(emit)
+
+                        if active and self.mesh is not None:
+                            # steady-state audit: the step must have
+                            # returned state (and pools) in exactly the rule
+                            # shardings it received them with
+                            self._check_steady_sharding(
+                                state, self._store.device_pools()
+                                if self._fused else None)
+                decode_emitted += emitted_this_step
+                slot_steps += len(active)
+
+                if ran or active:
+                    step_log.append({"step": step,
+                                     "prefill_rids": [t.req.rid for t in ran],
+                                     "chunks": len(ran),
+                                     "decoded": len(active),
+                                     "emitted": emitted_this_step})
+
+                # -- phase 3: completions (finished prefills + decodes) ------
+                with phases.span("complete"):
+                    for task in list(tasks):
+                        if task.chunk_idx < task.n_chunks:
+                            continue
+                        if self._store is not None:
+                            state, gen_inc = self._complete_paged(
+                                task, sched, state, now, outputs, histories,
+                                pending_host, pos_host, temps_host)
+                            generated += gen_inc
+                            tasks.remove(task)
+                            continue
+                        # prefill complete: graft the donor into its
+                        # reserved slot, sample the first token, occupy
+                        slot = task.slot
+                        temps_host[slot] = self._slot_temperature(task.req)
+                        first = int(np.asarray(self._sample(
+                            jnp.asarray(task.first_logits), self._next_key(),
+                            jnp.asarray(temps_host[slot:slot + 1])))[0])
+                        validate_donor(state, task.donor,
+                                       self.model.state_batch_axes(state))
+                        state = self._insert(state, task.donor, slot)
+                        sched.place(task.req, slot)
+                        sched.first_token(slot, now())
+                        generated += 1
+                        outputs[task.req.rid] = [first]
+                        pending_host[slot] = first
+                        pos_host[slot] = task.fill
+                        # committed token history (prompt + emitted): the
+                        # drafter's lookup corpus, reset on every slot reuse
+                        histories[slot] = [int(t) for t in
+                                           np.asarray(task.req.tokens)] \
+                            + [first]
+                        if self._spec_policy is not None:
+                            self._spec_policy.reset(slot)
+                        if self._drafter is not None:
+                            self._drafter.on_graft(task.req.rid, slot,
+                                                   histories[slot])
+                        tasks.remove(task)
+                        if sched.slot_done(slot):       # gen_len == 1 edge
+                            sched.finish(slot, now())
+                            state = self._reset(state, slot)
+                            if self._drafter is not None:
+                                self._drafter.on_finish(slot)
+                    for slot in list(active):
+                        if sched.slot_done(slot):
+                            rid_done = sched.slots[slot].request.rid
+                            sched.finish(slot, now())
+                            state = self._reset(state, slot)
+                            if self._store is not None:
+                                self._store.free(rid_done)
+                                self._act_stamp.pop(slot, None)
+                            if self._drafter is not None:
+                                self._drafter.on_finish(slot)
+                step_span.set(chunks=len(ran), decoded=len(active))
+                # an iteration whose whole queue was refused ran nothing
+                step_span.counted = bool(ran or active)
             if ran or active:
                 step += 1           # a unified step actually did device work
-            elif not sched.active_slots() and not tasks:
-                nxt = sched.next_arrival_s()
-                if nxt is not None and not sched.queue and not self._inbox:
-                    # idle until the next scheduled arrival
-                    time.sleep(max(0.0, min(nxt - now(), 0.05)))
 
         wall = max(now(), 1e-9)
         finished = sched.finished
@@ -1227,6 +1313,11 @@ class ServeEngine:
             "generated_tokens": generated,
             "decode_tokens_per_s": generated / wall,
             "steps": step,
+            # phase-2 device steps (decode or verify) and the slots they ran
+            "decode_steps": decode_steps + verify_steps,
+            "decode_slot_steps": slot_steps,
+            # host seconds and count per loop phase ({name: {"n", "s"}})
+            "phases": phases.report(),
             "wall_s": wall,
             "padded_slot_steps_steady": padded_steady,
             "prefill_chunk": chunk,

@@ -63,6 +63,7 @@ from __future__ import annotations
 import hashlib
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -71,6 +72,19 @@ from repro.dist.compression import (dequantize_absmax_int8,
 from repro.kernels.paged import ref as _pref
 
 from .slots import PageAllocator, PagesExhausted, SlotPages
+
+
+def serve_page_write(pool, rows, pids, ax: int):
+    """Write ``rows`` (``len(pids) * page`` rows) into pages ``pids`` of a
+    fused-layout pool whose token axis ``ax`` is split into (n_pages, page)."""
+    blocks = rows.astype(pool.dtype).reshape(
+        (pids.shape[0], pool.shape[ax + 1]) + rows.shape[1:])
+    idx = (slice(None),) * ax + (pids,)
+    return pool.at[idx].set(jnp.moveaxis(blocks, (0, 1), (ax, ax + 1)))
+
+
+# the page-store write as one named program (``jit_serve_page_write``)
+_page_write = jax.jit(serve_page_write, static_argnums=(3,))
 
 def selected_page_size() -> int:
     """Page size of the generated library's SELECTED cache_page_read
@@ -326,10 +340,7 @@ class PagedKVStore:
 
     def _pool_scatter(self, pool, ax, rows, pids):
         if self.fused:
-            blocks = rows.astype(pool.dtype).reshape(
-                (len(pids), self.page) + rows.shape[1:])
-            idx = (slice(None),) * ax + (jnp.asarray(pids, jnp.int32),)
-            return pool.at[idx].set(jnp.moveaxis(blocks, (0, 1), (ax, ax + 1)))
+            return _page_write(pool, rows, np.asarray(pids, np.int32), ax)
         return self._scatter(pool, rows, self._offsets(pids))
 
     # -- accounting (the admission/"budget" interface) -----------------------

@@ -34,7 +34,7 @@ from __future__ import annotations
 import heapq
 import logging
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
 
@@ -211,9 +211,13 @@ class RequestMetrics:
     bucket: int = 0                 # padded prompt length (length bucketing)
     tokens_out: int = 0
     ttft_s: float = 0.0             # arrival -> first token (queue + prefill)
-    prefill_s: float = 0.0          # step time attributed to prefill chunks
-    decode_s: float = 0.0           # step time attributed to decode tokens
-    decode_tokens_per_s: float = 0.0
+    queue_s: float = 0.0            # arrival -> first reservation, plus a
+                                    # preempted request's wait to its next
+    prefill_s: float = 0.0          # reservation -> first token
+    park_s: float = 0.0             # paged: time spent parked, holding its
+                                    # tokens with no lane to decode in
+    decode_tokens_per_s: float = 0.0   # tokens after the first over the lane
+                                       # time (latency - ttft - park_s)
     latency_s: float = 0.0          # arrival -> last token
     sla_s: float | None = None
     sla_met: bool | None = None     # None: no SLA attached
@@ -227,6 +231,9 @@ class RequestMetrics:
     preemptions: int = 0            # paged serving: times this request was
                                     # preempted on page exhaustion and
                                     # requeued as a continuation
+    # engine-clock time at which each emitted token reached the host (the
+    # tokens of one step share their step's stamp)
+    token_s: list[float] = field(default_factory=list)
 
 
 @dataclass
@@ -600,6 +607,35 @@ class PagedAdmission(CostModelAdmission):
         return True, "ok"
 
 
+def _admitted(req: Request, slot: int, step: int,
+              now_s: float | None) -> RequestMetrics:
+    """Metrics of a request reserved at ``now_s`` (None: not stamped)."""
+    return RequestMetrics(
+        rid=req.rid, slot=slot, prompt_len=req.prompt_len,
+        gen_len=req.gen_len, bucket=req.bucket or req.prompt_len,
+        sla_s=req.sla_s, admitted_at_step=step,
+        queue_s=0.0 if now_s is None else max(now_s - req.arrival_s, 0.0))
+
+
+def _first_token(req: Request, m: RequestMetrics, now_s: float) -> None:
+    """The first token reached the host at ``now_s``: TTFT, and the prefill
+    time since the reservation."""
+    m.ttft_s = max(now_s - req.arrival_s, 1e-9)
+    m.prefill_s = max(m.ttft_s - m.queue_s, 0.0)
+    m.tokens_out = 1
+    m.token_s = [now_s]
+
+
+def _finished(req: Request, m: RequestMetrics, now_s: float) -> None:
+    """The last token reached the host at ``now_s``: latency, the decode
+    rate over the time the request held a lane, and the SLA verdict."""
+    m.latency_s = max(now_s - req.arrival_s, 1e-9)
+    lane_s = m.latency_s - m.ttft_s - m.park_s
+    m.decode_tokens_per_s = max(m.tokens_out - 1, 0) / max(lane_s, 1e-9)
+    if m.sla_s is not None:
+        m.sla_met = m.latency_s <= m.sla_s
+
+
 @dataclass
 class _Slot:
     request: Request | None = None     # occupied: decoding
@@ -623,14 +659,13 @@ class Scheduler:
                                          recorded and dropped
       reserve(slot, req, step)         — slot enters prefill (chunks running)
       place(req, slot)                 — prefill done: slot occupied
-      first_token(slot, now)           — TTFT stamp
-      step_done(slot)                  — one real token decoded in this slot
-      attribute_step_time(...)         — split a shared step's wall time
-                                         between prefill and decode tokens
+      first_token(slot, now)           — TTFT stamp (queue + prefill)
+      step_done(slot, n, now)          — n real tokens decoded in this slot,
+                                         stamped with the step's time
       finish(slot, now) -> metrics     — request complete, slot freed
 
-    Paged mode adds a lane-less track: reserve_unplaced / place_parked /
-    first_token_unplaced / finish_unplaced (admission and parking are
+    Paged mode adds a lane-less track: reserve_unplaced / first_token_unplaced
+    / park / place_parked / finish_unplaced (admission and parking are
     page-count decisions, not lane decisions) and preempt / requeue_front
     (page exhaustion sends a decoding request back to the queue head as a
     resumable continuation).
@@ -648,6 +683,7 @@ class Scheduler:
         # paged serving: requests admitted WITHOUT a lane (prefilling into a
         # donor, or parked resident in pages awaiting a free lane)
         self.unplaced: dict[str, tuple[Request, RequestMetrics]] = {}
+        self._parked_at: dict[str, float] = {}    # rid -> when it parked
 
     # -- request stream -------------------------------------------------------
 
@@ -711,16 +747,15 @@ class Scheduler:
     def reserved_slots(self) -> list[int]:
         return [i for i, s in enumerate(self.slots) if s.reserved is not None]
 
-    def reserve(self, slot: int, req: Request, step: int) -> None:
+    def reserve(self, slot: int, req: Request, step: int,
+                now_s: float | None = None) -> None:
+        """Slot enters prefill; ``now_s`` stamps the request's queue wait."""
         s = self.slots[slot]
         if not s.free:
             raise ValueError(f"slot {slot} is not free")
         s.reserved = req
         self.admission_log.append({"step": step, "slot": slot, "rid": req.rid})
-        s.metrics = RequestMetrics(
-            rid=req.rid, slot=slot, prompt_len=req.prompt_len,
-            gen_len=req.gen_len, bucket=req.bucket or req.prompt_len,
-            sla_s=req.sla_s, admitted_at_step=step)
+        s.metrics = _admitted(req, slot, step, now_s)
 
     def place(self, req: Request, slot: int, step: int | None = None) -> None:
         s = self.slots[slot]
@@ -739,7 +774,8 @@ class Scheduler:
 
     # -- paged serving: lane-less admission, parking, preemption --------------
 
-    def reserve_unplaced(self, req: Request, step: int) -> RequestMetrics:
+    def reserve_unplaced(self, req: Request, step: int,
+                         now_s: float | None = None) -> RequestMetrics:
         """Admit a request WITHOUT reserving a lane (paged mode: the prefill
         runs in a donor, and a completed request may stay parked in pages).
         Logged with slot = -1; ``place_parked`` moves it into a lane later,
@@ -747,23 +783,28 @@ class Scheduler:
         if req.rid in self.unplaced:
             raise ValueError(f"request {req.rid!r} already unplaced")
         self.admission_log.append({"step": step, "slot": -1, "rid": req.rid})
-        m = RequestMetrics(
-            rid=req.rid, slot=-1, prompt_len=req.prompt_len,
-            gen_len=req.gen_len, bucket=req.bucket or req.prompt_len,
-            sla_s=req.sla_s, admitted_at_step=step)
+        m = _admitted(req, -1, step, now_s)
         self.unplaced[req.rid] = (req, m)
         return m
 
     def unplaced_metrics(self, rid: str) -> RequestMetrics:
         return self.unplaced[rid][1]
 
-    def place_parked(self, rid: str, slot: int) -> Request:
-        """Activate an unplaced (parked) request into a free lane, carrying
-        its metrics (TTFT was stamped at prefill completion, while parked)."""
+    def park(self, rid: str, now_s: float) -> None:
+        """An unplaced request finished its prefill with no free lane: it
+        waits, parked, from ``now_s`` until ``place_parked``."""
+        self._parked_at[rid] = now_s
+
+    def place_parked(self, rid: str, slot: int, now_s: float) -> Request:
+        """Activate an unplaced request into a free lane at ``now_s``,
+        carrying its metrics (TTFT was stamped at prefill completion); the
+        time since it parked, if it did, adds to its ``park_s``."""
         s = self.slots[slot]
         if not s.free:
             raise ValueError(f"slot {slot} is not free")
         req, m = self.unplaced.pop(rid)
+        if rid in self._parked_at:
+            m.park_s += now_s - self._parked_at.pop(rid)
         m.slot = slot
         s.request, s.metrics = req, m
         s.served += 1
@@ -773,20 +814,13 @@ class Scheduler:
         """TTFT stamp for a request finishing prefill without a lane: the
         first token exists (sampled from the last prefill chunk) whether or
         not a lane is free to decode the second one."""
-        req, m = self.unplaced[rid]
-        m.ttft_s = max(now_s - req.arrival_s, 1e-9)
-        m.tokens_out = 1
+        _first_token(*self.unplaced[rid], now_s)
 
     def finish_unplaced(self, rid: str, now_s: float) -> RequestMetrics:
         """Complete a request that never got (or no longer needs) a lane —
         the parked gen_len == 1 edge case."""
         req, m = self.unplaced.pop(rid)
-        m.latency_s = max(now_s - req.arrival_s, 1e-9)
-        decode_s = m.decode_s if m.decode_s > 0 \
-            else max(m.latency_s - m.ttft_s, 1e-9)
-        m.decode_tokens_per_s = max(m.tokens_out - 1, 0) / max(decode_s, 1e-9)
-        if m.sla_s is not None:
-            m.sla_met = m.latency_s <= m.sla_s
+        _finished(req, m, now_s)
         self.finished.append(m)
         return m
 
@@ -804,15 +838,18 @@ class Scheduler:
         return req, m
 
     def first_token(self, slot: int, now_s: float) -> None:
-        m = self.slots[slot].metrics
-        m.ttft_s = max(now_s - self.slots[slot].request.arrival_s, 1e-9)
-        m.tokens_out = 1
+        s = self.slots[slot]
+        _first_token(s.request, s.metrics, now_s)
 
-    def step_done(self, slot: int, n: int = 1) -> None:
+    def step_done(self, slot: int, n: int = 1,
+                  now_s: float | None = None) -> None:
         """``n`` target-model-emitted tokens landed in this slot this step
         (n > 1: a verify round accepted n-1 drafts + the corrected token;
-        rejected drafts are never counted)."""
-        self.slots[slot].metrics.tokens_out += n
+        rejected drafts are never counted), at ``now_s`` on the host."""
+        m = self.slots[slot].metrics
+        m.tokens_out += n
+        if now_s is not None:
+            m.token_s.extend([now_s] * n)
 
     def spec_round(self, slot: int, proposed: int, accepted: int) -> None:
         """Account one verify round for this slot's request."""
@@ -826,46 +863,10 @@ class Scheduler:
         return (s.request is not None
                 and s.metrics.tokens_out >= s.request.gen_len)
 
-    def attribute_step_time(self, t_step: float, prefill_tokens: int,
-                            decode_slots: list[int],
-                            decode_tokens: int | None = None
-                            ) -> tuple[float, float]:
-        """Split one shared step's wall time proportionally between the
-        prefill tokens (chunk work) and decode tokens it processed
-        (``decode_tokens`` defaults to one per active slot; a speculative
-        verify round passes the EMITTED count — accepted + corrected — so
-        the split tracks real output). The decode share is credited to EVERY
-        decoding request's ``decode_s`` (wall time is shared, not divided —
-        each request waited the full decode window); the prefill share is
-        returned for the engine to credit the prefilling request(s).
-        Without this split, a long prompt's chunks would silently inflate
-        its neighbours' reported decode-t/s denominators."""
-        if decode_tokens is None:
-            decode_tokens = len(decode_slots)
-        total = prefill_tokens + decode_tokens
-        if total == 0 or t_step <= 0:
-            return 0.0, 0.0
-        pre_share = t_step * prefill_tokens / total
-        dec_share = t_step - pre_share
-        for slot in decode_slots:
-            self.slots[slot].metrics.decode_s += dec_share
-        return pre_share, dec_share
-
-    def add_prefill_time(self, slot: int, seconds: float) -> None:
-        if self.slots[slot].metrics is not None:
-            self.slots[slot].metrics.prefill_s += seconds
-
     def finish(self, slot: int, now_s: float) -> RequestMetrics:
         s = self.slots[slot]
         m, req = s.metrics, s.request
-        m.latency_s = max(now_s - req.arrival_s, 1e-9)
-        # decode_s is attributed per shared step (prefill chunks excluded);
-        # fall back to wall-minus-ttft when no attribution ran (unit tests)
-        decode_s = m.decode_s if m.decode_s > 0 \
-            else max(m.latency_s - m.ttft_s, 1e-9)
-        m.decode_tokens_per_s = max(m.tokens_out - 1, 0) / max(decode_s, 1e-9)
-        if m.sla_s is not None:
-            m.sla_met = m.latency_s <= m.sla_s
+        _finished(req, m, now_s)
         s.request, s.reserved, s.metrics = None, None, None
         self.finished.append(m)
         return m

@@ -5,8 +5,9 @@ whole-prompt prefill across all four decode families (every chunk size shape:
 chunk=1, ragged final chunk, chunk >= prompt with bucket padding); the
 no-decode-stall acceptance property under a mixed trace with a long prompt
 arriving mid-run; the compiled-shape bound (len(buckets) + 1 per family);
-async arrival gating; proportional prefill/decode step-time attribution; and
-hypothesis property tests for the length-bucketing policy.
+async arrival gating; the measured per-request stamps (queue wait, prefill,
+parked time, one host time per token); and hypothesis property tests for the
+length-bucketing policy.
 """
 
 import hypothesis.strategies as st
@@ -275,52 +276,66 @@ def test_async_arrivals_gate_admission():
     assert late_m["latency_s"] < rep["wall_s"]
 
 
-# -- shared-step time attribution ----------------------------------------------
+# -- measured per-request stamps -------------------------------------------------
+
+
+def assert_measured_stamps(m):
+    """What the stamps of one finished request must satisfy: the queue wait
+    and the prefill make up TTFT, the parked time fits between the first
+    token and the last, and each token has its host time, in order."""
+    assert m["queue_s"] + m["prefill_s"] == pytest.approx(m["ttft_s"],
+                                                          abs=1e-6)
+    assert m["ttft_s"] + m["park_s"] <= m["latency_s"] + 1e-9
+    assert len(m["token_s"]) == m["tokens_out"]
+    assert all(b >= a for a, b in zip(m["token_s"], m["token_s"][1:]))
 
 
 def test_step_time_attribution_split():
-    """ISSUE 5 satellite: shared-step wall time is split proportionally
-    between prefill chunk tokens and decode tokens — a neighbour's prefill
-    must not inflate a request's decode-t/s denominator."""
-    sched = Scheduler(3)
-    a = Request(rid="a", tokens=np.arange(4), gen_len=5)
-    b = Request(rid="b", tokens=np.arange(4), gen_len=5)
+    """The scheduler's stamps, on a lane and parked: queue wait to the
+    reservation, prefill to the first token, the time parked, one host time
+    per token (a verify round's tokens share theirs), and decode tokens/s
+    over the time the request held a lane."""
+    from dataclasses import asdict
+
+    sched = Scheduler(2)
+    a = Request(rid="a", tokens=np.arange(4), gen_len=5, arrival_s=0.1)
+    b = Request(rid="b", tokens=np.arange(4), gen_len=2, arrival_s=0.1)
     sched.submit(a, 0.0)
     sched.submit(b, 0.0)
-    sched.place(sched.next_admissible(0.0), 0, step=0)
-    sched.place(sched.next_admissible(0.0), 1, step=0)
-    sched.first_token(0, 0.1)
-    sched.first_token(1, 0.1)
+    sched.release(0.2)
+    req = sched.next_admissible(0.5)
+    sched.reserve(0, req, step=0, now_s=0.5)
+    sched.place(req, 0)
+    sched.first_token(0, 0.9)
+    sched.step_done(0, now_s=1.0)
+    sched.step_done(0, n=2, now_s=1.2)
+    sched.step_done(0, now_s=1.4)
+    ma = sched.finish(0, 1.5)
+    assert ma.queue_s == pytest.approx(0.4)
+    assert ma.prefill_s == pytest.approx(0.4)
+    assert ma.ttft_s == pytest.approx(0.8)
+    assert ma.park_s == 0.0
+    assert ma.token_s == [0.9, 1.0, 1.2, 1.2, 1.4]
+    assert ma.decode_tokens_per_s == pytest.approx(4 / (1.4 - 0.8))
 
-    # one shared step: 8 prefill tokens (a chunk for some third request) + 2
-    # decode tokens -> decode gets 2/10 of the wall, prefill 8/10
-    pre, dec = sched.attribute_step_time(1.0, 8, [0, 1])
-    assert pre == pytest.approx(0.8)
-    assert dec == pytest.approx(0.2)
-    assert sched.slots[0].metrics.decode_s == pytest.approx(0.2)
-    assert sched.slots[1].metrics.decode_s == pytest.approx(0.2)
-
-    # decode-only step: all of it is decode time
-    sched.attribute_step_time(0.5, 0, [0, 1])
-    assert sched.slots[0].metrics.decode_s == pytest.approx(0.7)
-
-    # finish() computes decode-t/s from ATTRIBUTED decode seconds, not from
-    # latency - ttft (which would include the neighbour's prefill wall)
-    for _ in range(4):
-        sched.step_done(0)
-    m = sched.finish(0, 10.0)
-    assert m.decode_tokens_per_s == pytest.approx(4 / 0.7)
-    # the un-attributed fallback would have been 4 / (10 - 0.1)
-    assert m.decode_tokens_per_s > 4 / (10.0 - 0.1)
-
-    # zero-work step is a no-op
-    assert sched.attribute_step_time(1.0, 0, []) == (0.0, 0.0)
+    # paged: reserved with no lane, parked from its first token to a lane
+    req = sched.next_admissible(0.3)
+    sched.reserve_unplaced(req, step=1, now_s=0.3)
+    sched.first_token_unplaced("b", 0.7)
+    sched.park("b", 0.7)
+    sched.place_parked("b", 1, 1.2)
+    sched.step_done(1, now_s=1.3)
+    mb = sched.finish(1, 1.3)
+    assert mb.park_s == pytest.approx(0.5)
+    assert mb.decode_tokens_per_s == pytest.approx(1 / (1.2 - 0.6 - 0.5))
+    for m in (ma, mb):
+        assert_measured_stamps(asdict(m))
 
 
 def test_engine_attributes_prefill_and_decode_time():
-    """Engine-level: per-request prefill_s/decode_s are populated and a
-    request that decoded while a long neighbour prefilled reports decode_s
-    well under its wall-clock decode window."""
+    """Engine-level: every finished request carries measured stamps, the
+    long prompt's prefill is timed from its reservation, and decode tokens/s
+    is taken over the time each request held a lane."""
     import jax
 
     jax.clear_caches()
@@ -334,14 +349,16 @@ def test_engine_attributes_prefill_and_decode_time():
     rep = eng.run(reqs)
     assert rep["requests"] == 2
     per = {m["rid"]: m for m in rep["per_request"]}
+    for m in per.values():
+        assert_measured_stamps(m)
+        assert m["park_s"] == 0.0                  # lanes never park
+        lane_s = m["latency_s"] - m["ttft_s"]
+        assert m["decode_tokens_per_s"] == pytest.approx(
+            (m["tokens_out"] - 1) / lane_s)
+    # the long prompt ran 32 / chunk chunks between reservation and its
+    # first token; r0 decoded beside them, one stamp per step
     assert per["long"]["prefill_s"] > 0
-    assert per["r0"]["decode_s"] > 0
-    # r0's attributed decode time excludes the long prefill's share: it is
-    # strictly smaller than its naive wall window (latency - ttft)
-    wall_window = per["r0"]["latency_s"] - per["r0"]["ttft_s"]
-    assert per["r0"]["decode_s"] < wall_window
-    assert per["r0"]["decode_tokens_per_s"] > \
-        (per["r0"]["tokens_out"] - 1) / wall_window
+    assert len(set(per["r0"]["token_s"])) == per["r0"]["tokens_out"]
 
 
 # -- length-bucketing policy property tests ------------------------------------

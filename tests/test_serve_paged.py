@@ -409,6 +409,16 @@ def test_preemption_returns_exact_tokens():
     assert rep["outputs"] == want["outputs"]
     assert rep["paged"]["preemptions"] >= 1
     assert any(e["preemptions"] >= 1 for e in rep["per_request"])
+    for m in rep["per_request"]:
+        # a preempted request's queue wait adds its wait to re-admission
+        # to the one before its first token; its tokens keep their stamps
+        wait_and_prefill = m["queue_s"] + m["prefill_s"]
+        if m["preemptions"]:
+            assert wait_and_prefill > m["ttft_s"]
+        else:
+            assert wait_and_prefill == pytest.approx(m["ttft_s"], abs=1e-6)
+        assert len(m["token_s"]) == m["tokens_out"] == m["gen_len"]
+        assert m["token_s"] == sorted(m["token_s"])
 
 
 def test_int8_paged_engine_smoke():
