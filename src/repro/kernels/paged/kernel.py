@@ -5,21 +5,26 @@ The lane-gather elimination behind the ``attention_decode_paged`` /
 pages into a contiguous lane and running ``attention_decode`` there, the
 kernel walks the PAGE POOL directly. The block table and per-slot kv_len
 arrive as scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``), so
-each K/V BlockSpec index map can translate a *logical* key-block index into
-a *physical* pool row before the DMA is issued — the page indirection is
-folded into the Pallas pipeline itself and the only HBM traffic is the
-touched pages.
+each K/V BlockSpec index map translates a *logical* page index into a
+*physical* pool page before the DMA is issued — the page indirection is
+folded into the Pallas pipeline itself.
 
-Grid: (B, KH, n_j) where n_j = max_pages * (page // block_k); the j axis is
-"arbitrary" (sequential) so the online-softmax (m, l, acc) scratch carries
-across key blocks exactly as in the flash-attention forward. GQA is folded
-by shaping q as (B, KH, group * SQ, D): all of a KV head's query heads ride
-in the q block's row axis, so each pool page is fetched once per KV head.
+Schedule: grid (B, KH // hg, P), P the table width. One grid step takes
+one slot, one table page and a group of ``hg`` KV heads: all of them
+whenever their double-buffered pages fit the VMEM budget, so the step count
+follows slots and pages, not heads x key blocks. Inside the page the body
+walks ``block_k``-row key sub-blocks (the UPD candidates) in a loop that
+stops at kv_len, each one a head-batched score and readout product feeding
+an f32 online softmax whose (m, l, acc) scratch carries across the
+sequential page axis as in the flash-attention forward. GQA is folded by
+shaping q as (B, KH, group * SQ, D): all of a KV head's query heads ride in
+the q block's row axis, so each pool page is fetched once per KV head.
 
-Blocks past a slot's kv_len are skipped by a block-level early exit on the
-prefetched length; their table entries must still hold a VALID page id (the
-serving layer points them at a scratch page) because the index map runs
-unconditionally. kv_len == 0 rows finalize to exactly 0 (l stays 0).
+Nothing is fetched past a slot's kv_len: the index maps clamp a page past
+the last live one to that last live page, and a block index that repeats
+from one grid step to the next issues no copy. Table entries must still
+hold VALID page ids (the serving layer points them at a scratch page).
+kv_len == 0 rows finalize to exactly 0 (l stays 0).
 """
 
 from __future__ import annotations
@@ -31,13 +36,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from ..common import LANES, ROW_BLOCK_VMEM_BYTES, round_up
+
 NEG_INF = -1e30
-_LANES = 128
+
+
+def head_group(kh: int, page: int, d: int, itemsize: int) -> int:
+    """KV heads per grid step for a pool of ``kh`` heads of ``page`` x ``d``
+    rows: the largest divisor of ``kh`` whose double-buffered K and V page
+    blocks fit :data:`ROW_BLOCK_VMEM_BYTES` (a row takes whole 128-lane
+    tiles in VMEM)."""
+    head_page = 2 * 2 * page * round_up(d, LANES) * itemsize   # K, V; 2 buffers
+    return max(g for g in range(1, kh + 1)
+               if kh % g == 0 and (g == 1 or g * head_page <= ROW_BLOCK_VMEM_BYTES))
+
+
+def _update(q, k, v, k0, kvl, m_scr, l_scr, acc_scr, *, scale: float,
+            sq: int):
+    """One online-softmax step of every head in the group over the key rows
+    at logical positions [k0, k0 + bk). q (hg, rq, D); k/v (hg, bk, D)."""
+    hg, rq, _ = q.shape
+    bk = k.shape[1]
+    ct = jnp.promote_types(q.dtype, k.dtype)
+    s = jax.lax.dot_general(
+        q.astype(ct), k.astype(ct), (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale     # (hg, rq, bk)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (hg, rq, bk), 2)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hg, rq, bk), 1)
+    # span rows are ends-aligned at kv_len: row r sits at kvl - sq + r%sq
+    valid = k_pos <= kvl - sq + jax.lax.rem(row, sq)
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_scr[:, :, :1]                             # (hg, rq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = corr * l_scr[:, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
 def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, sq: int,
-                  bk: int, n_j: int):
+                  m_scr, l_scr, acc_scr, *, scale: float, sq: int, page: int,
+                  bk: int, n_p: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
     kvl = len_ref[b]
@@ -48,41 +92,24 @@ def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # key blocks are visited in logical order (page-major, sub-block minor),
-    # so this block covers logical key positions [j*bk, (j+1)*bk)
-    @pl.when(j * bk < kvl)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)              # (rq, D)
-        k = k_ref[0].astype(jnp.float32)                 # (bk, D)
-        v = v_ref[0].astype(jnp.float32)
-        rq = q.shape[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (rq, bk)
-        k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (rq, bk), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (rq, bk), 0)
-        # span rows are ends-aligned at kv_len: row r sits at kvl - sq + r%sq
-        q_pos = kvl - sq + jax.lax.rem(row, sq)
-        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+    # this step's page holds logical keys [j*page, (j+1)*page); its live
+    # sub-blocks are those below kv_len, none once the page lies past it
+    n_sub = jnp.clip(pl.cdiv(kvl - j * page, bk), 0, page // bk)
 
-        m_prev = m_scr[:, :1]                            # (rq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(k_pos <= q_pos, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    def sub(t, carry):
+        off = pl.multiple_of(t * bk, bk)
+        _update(q_ref[0], k_ref[:, pl.ds(off, bk), :],
+                v_ref[:, pl.ds(off, bk), :], j * page + off, kvl,
+                m_scr, l_scr, acc_scr, scale=scale, sq=sq)
+        return carry
 
-    @pl.when(j == n_j - 1)
+    jax.lax.fori_loop(0, n_sub, sub, 0)
+
+    @pl.when(j == n_p - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         o = acc_scr[...] / jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = jnp.where(l > 0.0, o, 0.0).astype(o_ref.dtype)
+        o_ref[0] = jnp.where(l > 0.0, o, 0.0).astype(o_ref.dtype)
 
 
 def paged_attention_4d(q, k_flat, v_flat, tables, kv_len, *, sq: int,
@@ -93,34 +120,31 @@ def paged_attention_4d(q, k_flat, v_flat, tables, kv_len, *, sq: int,
     int32 page ids; kv_len: (B,) int32. Returns (B, KH, RQ, D)."""
     b, kh, rq, d = q.shape
     assert page % block_k == 0, (page, block_k)
-    spp = page // block_k                       # key sub-blocks per page
     n_p = tables.shape[1]
-    n_j = n_p * spp
+    hg = head_group(kh, page, d, jnp.dtype(k_flat.dtype).itemsize)
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    def kv_idx(b_, h_, j, tab, _len):
-        # physical block index into the row-flattened pool, in bk units:
-        # page id * sub-blocks-per-page + sub-block within the page
-        return (h_, tab[b_, j // spp] * spp + jax.lax.rem(j, spp), 0)
+    def kv_idx(b_, g, j, tab, ln):
+        # the step's page, clamped to the slot's last live page: past it the
+        # block index repeats, and a repeated block index issues no DMA
+        last = jnp.maximum(pl.cdiv(ln[b_], page) - 1, 0)
+        return (g, tab[b_, jnp.minimum(j, last)], 0)
 
+    q_spec = pl.BlockSpec((1, hg, rq, d), lambda b_, g, j, tab, ln: (b_, g, 0, 0))
+    kv_spec = pl.BlockSpec((hg, page, d), kv_idx)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, n_j),
-        in_specs=[
-            pl.BlockSpec((1, 1, rq, d), lambda b_, h_, j, tab, ln: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rq, d),
-                               lambda b_, h_, j, tab, ln: (b_, h_, 0, 0)),
+        grid=(b, kh // hg, n_p),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rq, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((rq, _LANES), jnp.float32),   # running denominator
-            pltpu.VMEM((rq, d), jnp.float32),        # output accumulator
+            pltpu.VMEM((hg, rq, LANES), jnp.float32),   # running max
+            pltpu.VMEM((hg, rq, LANES), jnp.float32),   # running denominator
+            pltpu.VMEM((hg, rq, d), jnp.float32),       # output accumulator
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale=sc, sq=sq, bk=block_k,
-                               n_j=n_j)
+    kernel = functools.partial(_paged_kernel, scale=sc, sq=sq, page=page,
+                               bk=block_k, n_p=n_p)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -1011,6 +1011,9 @@ class ServeEngine:
         slot_steps = 0              # per-slot phase-2 participations
         decode_steps = 0            # plain (k=0) decode steps
         verify_steps = 0            # speculative verify rounds
+        # fused decode steps: table pages the active slots' kv_len covers,
+        # and the block table's pages (slots x width)
+        live_pages = table_pages = 0
         spec_proposed_total = 0
         spec_accepted_total = 0
         spec_slot_rounds = 0        # (slot, round) pairs that speculated
@@ -1096,7 +1099,15 @@ class ServeEngine:
                                     f"{int(pos_host[active].max())} overran "
                                     f"max_len={self.max_len}")
                             K = int(k_vec.max())
-                            pos_vec = jnp.asarray(pos_host, jnp.int32)
+                            pos_in = pos_host
+                            if self._fused:
+                                # idle rows hold an all-scratch table: give
+                                # them position 0, so the kernel walks one
+                                # key there instead of a finished request's
+                                # length
+                                pos_in = np.zeros_like(pos_host)
+                                pos_in[active] = pos_host[active]
+                            pos_vec = jnp.asarray(pos_in, jnp.int32)
                             temps = jnp.asarray(temps_host)
                             tables = pools = None
                             if self._fused:
@@ -1125,6 +1136,12 @@ class ServeEngine:
                                     logits, self._next_key(), temps))
                                 t_tok = now()   # the step's tokens are here
                                 decode_steps += 1
+                                if self._fused:
+                                    live_pages += int(
+                                        (pos_host[active]
+                                         // self._store.page + 1).sum())
+                                    table_pages += (self.batch
+                                                    * self._table_width)
                                 for slot in active:
                                     rid = sched.slots[slot].request.rid
                                     sched.step_done(slot, now_s=t_tok)
@@ -1316,6 +1333,10 @@ class ServeEngine:
             # phase-2 device steps (decode or verify) and the slots they ran
             "decode_steps": decode_steps + verify_steps,
             "decode_slot_steps": slot_steps,
+            # over the fused plain decode steps: the table pages that hold
+            # the active slots' keys, and all the block table's pages
+            "decode_live_pages": live_pages,
+            "decode_table_pages": table_pages,
             # host seconds and count per loop phase ({name: {"n", "s"}})
             "phases": phases.report(),
             "wall_s": wall,

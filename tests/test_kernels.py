@@ -50,6 +50,48 @@ def test_flash_attention_sweep(b, h, kh, sq, sk, d, causal, dt):
                                np.asarray(want, np.float32), **_tol(dt))
 
 
+@pytest.mark.parametrize("h,kh,sq,vmem", [
+    (4, 4, 1, None),         # MHA decode
+    (10, 2, 1, None),        # GQA, group 5 (qwen3-14b's 40/8)
+    (4, 2, 4, None),         # verify span of 4, group 2
+    (4, 4, 1, 160 << 10),    # a VMEM budget that splits the heads
+])
+@pytest.mark.parametrize("block_k", [16, 64, 256])
+@pytest.mark.parametrize("dt", ["float32", "bfloat16"])
+def test_paged_attention_sweep(h, kh, sq, vmem, block_k, dt, monkeypatch):
+    """The Pallas block-table kernel (interpret) against the jnp reference:
+    slots at kv_len 0, 1, a page boundary and the whole table, scratch-padded
+    table tails, and one page id in two slots' tables."""
+    from repro.kernels.paged import kernel, ops, ref
+
+    page, n_p, n_pages, d = 64, 10, 24, 16
+    scratch = n_pages - 1
+    if vmem is not None:
+        monkeypatch.setattr(kernel, "ROW_BLOCK_VMEM_BYTES", vmem)
+        jax.clear_caches()
+    hg = kernel.head_group(kh, page, d, jnp.dtype(dt).itemsize)
+    assert hg == (kh if vmem is None else 1 + (dt == "bfloat16"))
+    perm = RNG.permutation(scratch)
+    tables = np.full((4, n_p), scratch, np.int32)
+    tables[1, :1] = perm[:1]
+    tables[2, :2] = perm[1:3]
+    tables[3] = np.concatenate([perm[3:12], perm[:1]])   # shares slot 1's page
+    kv_len = np.asarray([0, 1, 2 * page, n_p * page], np.int32)
+    q = _arr((4, h, sq, d), dt, -1, 1)
+    k_pool = _arr((kh, n_pages, page, d), dt, -1, 1)
+    v_pool = _arr((kh, n_pages, page, d), dt, -1, 1)
+    try:
+        got = ops.paged_attention(q, k_pool, v_pool, tables, kv_len,
+                                  block_k=block_k, interpret=True)
+    finally:
+        jax.clear_caches()
+    want = ref.paged_attention_ref(q, k_pool, v_pool, tables, kv_len)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    assert not np.asarray(got[0], np.float32).any()      # kv_len 0 gives 0
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **_tol(dt))
+
+
 @pytest.mark.parametrize("n", [64, 1000, 4096, 100_000])
 @pytest.mark.parametrize("dt", ["float32", "int32"])
 def test_range_count_sweep(n, dt):

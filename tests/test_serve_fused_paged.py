@@ -75,6 +75,25 @@ def test_fused_matches_lane_all_kv_families(arch, enc_len):
     assert got["paged"]["resident_requests_peak"] > 2
 
 
+def test_fused_decode_page_counters():
+    """The report's page counters over the fused decode steps: the pages
+    that hold each active slot's keys (a slot at position p reads p + 1
+    rows, ceil((p + 1) / page) pages), and the whole block table's."""
+    cfg = get_config("qwen1.5-0.5b").reduced()
+    page, prompt = 8, 8
+    reqs = _requests(cfg, [9, 5, 12, 3], prompt_len=prompt)
+    got = _run(cfg, reqs, fused=True, page_size=page, prefix_sharing=False)
+    assert got["paged"]["preemptions"] == 0
+    # the prefill serves the first token, one decode step each after it
+    positions = [p for out in got["outputs"].values()
+                 for p in range(prompt, prompt + len(out) - 1)]
+    assert len(positions) == got["decode_slot_steps"]
+    assert got["decode_live_pages"] == sum(-(-(p + 1) // page)
+                                           for p in positions)
+    assert got["decode_table_pages"] == got["decode_steps"] * 2 * (24 // page)
+    assert 0 < got["decode_live_pages"] < got["decode_table_pages"]
+
+
 def test_fused_sampled_matches_lane():
     """Sampled decoding (temperature + top-k) draws the SAME per-step keys:
     the fused pool read must not change a single draw vs lane activation."""

@@ -181,18 +181,30 @@ def test_flash_backward_compiles_for_v5e(one_chip, dt):
         assert name in text, name
 
 
-@pytest.mark.parametrize("page,block_k", [(64, 16), (64, 64)])
+# (slots, heads, KV heads, pool pages, table width, head dim)
+_PAGED_SHAPES = [
+    pytest.param(64, 16, (8, H, H, 64, 4, HD), id="64-16"),
+    pytest.param(64, 64, (8, H, H, 64, 4, HD), id="64-64"),
+    # the chat cell: qwen1.5-0.5b, 8 slots of max_len 1536, the default pool
+    pytest.param(64, 16, (8, 16, 16, 384, 24, 64), id="chat"),
+    # GQA: qwen3-14b's 40 query heads over 8 KV heads of 128
+    pytest.param(64, 16, (8, 40, 8, 384, 24, 128), id="gqa"),
+]
+
+
+@pytest.mark.parametrize("page,block_k,shape", _PAGED_SHAPES)
 @pytest.mark.parametrize("dt", DTYPES)
-def test_paged_decode_compiles_for_v5e(one_chip, dt, page, block_k):
+def test_paged_decode_compiles_for_v5e(one_chip, dt, page, block_k, shape):
     from repro.kernels.paged.ops import paged_attention
 
-    n_pages, slots, per_slot = 64, 8, 4
-    _compile(one_chip,
-             lambda q, k, v, t, n: paged_attention(q, k, v, t, n,
-                                                   block_k=block_k),
-             ((slots, H, 1, HD), dt), ((H, n_pages, page, HD), dt),
-             ((H, n_pages, page, HD), dt), ((slots, per_slot), jnp.int32),
-             ((slots,), jnp.int32))
+    slots, h, kh, n_pages, per_slot, hd = shape
+    compiled = _compile(
+        one_chip,
+        lambda q, k, v, t, n: paged_attention(q, k, v, t, n, block_k=block_k),
+        ((slots, h, 1, hd), dt), ((kh, n_pages, page, hd), dt),
+        ((kh, n_pages, page, hd), dt), ((slots, per_slot), jnp.int32),
+        ((slots,), jnp.int32))
+    assert "tsl_paged_attention" in compiled.as_text()
 
 
 def test_range_count_compiles_for_v5e(one_chip):
