@@ -2,16 +2,20 @@
 whether or not earlier ones have finished, and the engine serves them.
 
 Set-up builds the engine the serving launcher builds (paged, fused, the
-bound library, greedy sampling), hands it weights drawn from the seed, and
-warms up every shape the window will run. The window is the engine's own
-``run`` over the schedule: arrivals cover ``--seconds`` seconds from the
-engine's clock start, and the engine serves every arrival to its end (a
-tail counts every request). Then the engine is freed and the reference
-checks a sample of what it served.
+bound library, greedy sampling), over the configuration's mesh where it
+names one, hands it weights drawn from the seed (on a mesh, into the
+shardings of the engine's own parameters, so the engine must build those
+as it serves them), and warms up every shape the window will run. The
+window is the engine's own ``run`` over the schedule: arrivals cover
+``--seconds`` seconds from the engine's clock start, and the engine serves
+every arrival to its end (a tail counts every request). Then the engine is
+freed and the reference checks a sample of what it served, on weights laid
+out as the engine's were (on a mesh, no chip ever holds the whole model).
 """
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
 import threading
@@ -20,7 +24,7 @@ import time
 import jax
 import numpy as np
 
-from .. import check, loadgen, weights
+from .. import check, loadgen, spans, weights
 from ..harness import Ctx, check_entry, device_info
 from ..spec import Dims, arch_config
 from ..trace import Tracer
@@ -56,11 +60,11 @@ class CompileCounter:
         jax.monitoring.unregister_event_listener(self._on_event)
 
 
-def build_engine(cfg, serve: dict, mix: dict, seed: int):
+def build_engine(cfg, serve: dict, mix: dict, seed: int, mesh=None):
     """The launcher's engine (``launch/serve.build_engine``) with the
-    configuration's page budget and prefill concurrency: the slot table is
-    sized for the mix's longest prompt, padded to its bucket, plus its
-    longest output."""
+    configuration's page budget and prefill concurrency, over ``mesh``
+    where one is given: the slot table is sized for the mix's longest
+    prompt, padded to its bucket, plus its longest output."""
     from repro.serve import (BucketPolicy, PagedConfig, SamplingConfig,
                              ServeEngine)
 
@@ -77,7 +81,8 @@ def build_engine(cfg, serve: dict, mix: dict, seed: int):
         sampling=SamplingConfig(), seed=seed, buckets=buckets,
         paged=PagedConfig(
             hbm_budget_bytes=serve.get("hbm_budget_bytes"),
-            max_inflight_prefills=serve.get("max_inflight_prefills")))
+            max_inflight_prefills=serve.get("max_inflight_prefills")),
+        mesh=mesh)
 
 
 def served_requests(plan, served: dict) -> list[dict]:
@@ -99,12 +104,16 @@ def warm_up(engine, plan) -> None:
     engine.run([Request(rid=f"warm{i}", tokens=plan[0].prompt[:1].repeat(n),
                         gen_len=3) for i, n in enumerate(picks)])
     store = engine._store
-    donor = engine._new_donor()
-    for n in lens:
-        rid = f"warm-store-{n}"
-        store.attach(rid, prompt_rows=engine.cfg.decode_prefix + n)
-        store.store_donor(rid, donor, fill=engine.cfg.decode_prefix + n)
-        store.free(rid)
+    # the page-store writes run under the mesh, as the engine's loop runs
+    # them, so they compile the programs the window uses
+    with (jax.set_mesh(engine.mesh) if engine.mesh is not None
+          else contextlib.nullcontext()):
+        donor = engine._new_donor()
+        for n in lens:
+            rid = f"warm-store-{n}"
+            store.attach(rid, prompt_rows=engine.cfg.decode_prefix + n)
+            store.store_donor(rid, donor, fill=engine.cfg.decode_prefix + n)
+            store.free(rid)
     jax.block_until_ready(store.device_pools())
 
 
@@ -116,11 +125,24 @@ def run(ctx: Ctx) -> dict:
     serve, mix = ctx.config["serve"], ctx.mix
     plan = loadgen.open_loop(ctx.seed, ctx.seconds, mix, dims.vocab)
 
-    engine = build_engine(cfg, serve, mix, ctx.seed)
+    mesh = None
+    if ctx.mesh_shape is not None:
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(*ctx.mesh_shape)
+    engine = build_engine(cfg, serve, mix, ctx.seed, mesh)
     weights.check_layout(dims, jax.eval_shape(engine.model.init,
                                               jax.random.PRNGKey(0)))
+    # on a mesh, the weights go where the program put its own: the
+    # benchmark never restates the program's sharding rules, so the engine
+    # has to build its own parameters, laid out on the mesh as it serves
+    # them, before they are replaced. On one device the draw stays
+    # uncommitted, as the program's own init is: weights committed to the
+    # device would make the engine's programs compile again in the window
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree.map(lambda a: a.sharding, engine.params)
     engine.params = None                 # the program's own init, freed
-    engine.params = weights.make_params(dims, ctx.seed)
+    engine.params = weights.make_params(dims, ctx.seed, shardings=shardings)
     warm_up(engine, plan)
     jit_before = engine.jit_cache_sizes()
     counter = CompileCounter()
@@ -148,20 +170,25 @@ def run(ctx: Ctx) -> dict:
             timer.join()
     jit_after = engine.jit_cache_sizes()
     device = device_info(ctx.cell["chips"])
-    summary = None
+    summary, step_spans = None, None
     if timer:
+        step_spans = spans.reduce_spans(timer.path)
         summary = tracer.collect(timer.path, ctx.keep_trace)
 
     finished = {m["rid"]: m for m in report["per_request"]}
     served = report["outputs"]
     refused = report["refused"]
+    counts = {k: report[k] for k in
+              ("phases", "decode_steps", "decode_slot_steps",
+               "decode_live_pages", "decode_table_pages")}
+    mesh_report = report.get("mesh")
     del engine, report
     gc.collect()
 
     # -- the check: the reference over a sample of what was served ---------
     sample = check.sample_served(served_requests(plan, served), ctx.seed,
                                  mix["check"])
-    params = weights.make_params(dims, ctx.seed)
+    params = weights.make_params(dims, ctx.seed, shardings=shardings)
     t_ref = time.perf_counter()
     got = check.check_served(params, dims, sample)
     ref_s = time.perf_counter() - t_ref
@@ -190,6 +217,12 @@ def run(ctx: Ctx) -> dict:
         "window_traces": counter.traces, "window_compiles": counter.compiles,
         "jit_cache_before": jit_before, "jit_cache_after": jit_after,
     }
+    if mesh_report is not None:
+        counters["mesh"] = {
+            k: mesh_report[k]
+            for k in ("axes", "reshard_events", "param_bytes_per_shard")}
+    if summary is not None:
+        counters["collective_s"] = summary.collective_s
     ctx.log(f"counters {counters}")
     # each request as (arrival s, prompt, output, ttft s, latency s), so a
     # run's statistics can be read again from its log
@@ -202,11 +235,13 @@ def run(ctx: Ctx) -> dict:
         "attempted": len(plan),
         "failed": len(set(incomplete) | {r["rid"] for r in refused}),
         "device": device, "check": checks, "counters": counters,
-        "trace": summary, "setup_s": setup_s, "seconds": ctx.seconds,
+        "trace": summary, "spans": step_spans, "setup_s": setup_s,
+        "seconds": ctx.seconds, "slots": serve["slots"], **counts,
         "requests": [{"rid": p.rid, "arrival_s": p.arrival_s,
                       "prompt_len": len(p.prompt), "gen_len": p.gen_len,
                       **({k: finished[p.rid][k] for k in
-                          ("ttft_s", "latency_s", "tokens_out")}
+                          ("ttft_s", "latency_s", "tokens_out", "queue_s",
+                           "park_s", "token_s")}
                          if p.rid in finished else {})}
                      for p in plan],
     }

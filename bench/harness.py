@@ -43,6 +43,12 @@ class Ctx:
     work_dir: Path = WORK_DIR
     keep_trace: Path | None = None    # where to keep the raw trace, gzipped
 
+    def __post_init__(self):
+        # (data, model) from the configuration's serve.mesh, or None; a
+        # mesh that does not cover the cell's chips raises here, before
+        # any set-up
+        self.mesh_shape = spec.mesh_shape(self.config, self.cell["chips"])
+
     def log(self, msg: str) -> None:
         print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
@@ -162,16 +168,16 @@ def main(args, t_start: float) -> int:
               f"chip(s); JAX found {len(devs)} {platform} device(s)",
               file=sys.stderr)
         return 2
-    cache = enable_cache()
-    lib = bind_chip_library()
     ctx = Ctx(bench=bench, cell=cell,
               config=spec.config_file(bench, cell["config"]),
               mix=spec.traffic_file(cell["traffic"]), seed=args.seed,
               seconds=float(args.seconds), trace=bool(args.trace),
               t_start=t_start, keep_trace=args.keep_trace)
+    cache = enable_cache()
+    lib = bind_chip_library()
     ctx.log(f"platform={platform} device_kind={devs[0].device_kind!r} "
             f"count={len(devs)} library={lib.TARGET_NAME} cache={cache} "
-            f"workload={cell['name']} seed={args.seed} "
+            f"workload={cell['name']} mesh={ctx.mesh_shape} seed={args.seed} "
             f"seconds={args.seconds} trace={args.trace}")
     result = execute(ctx)
     report(result)

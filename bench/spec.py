@@ -101,6 +101,25 @@ def cell_metrics(bench: dict, cell_name: str, kind: str) -> list[dict]:
             if cell_name in m.get("workloads", [cell_name])]
 
 
+def mesh_shape(config: dict, chips: int) -> tuple[int, int] | None:
+    """The ``(data, model)`` mesh the configuration serves over, or None
+    where its serving block names no mesh (the program then runs unmeshed,
+    as on one chip). Raises unless the mesh covers exactly the cell's chips,
+    so a cell that would leave a chip idle, or ask for one it does not
+    have, is refused before set-up."""
+    mesh = config.get("serve", {}).get("mesh")
+    if mesh is None:
+        return None
+    if set(mesh) != {"data", "model"}:
+        raise ValueError(f"serve.mesh {mesh} must give exactly 'data' and "
+                         f"'model'")
+    shape = (int(mesh["data"]), int(mesh["model"]))
+    if min(shape) < 1 or shape[0] * shape[1] != chips:
+        raise ValueError(f"serve.mesh {mesh} spans {shape[0] * shape[1]} "
+                         f"chip(s); the cell asks for {chips}")
+    return shape
+
+
 def arch_config(config: dict):
     """The program's ArchConfig for a configuration file: the registry entry
     named by ``arch``, with every published key of the file applied."""

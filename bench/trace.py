@@ -1,11 +1,15 @@
 """Device trace: take one with the JAX profiler, and reduce it to the numbers
 the per-layer metrics read (busy and idle time, time by operation and by
-kernel name, the longest idle gaps and what the host was doing in them).
+kernel name, time in collectives, the longest idle gaps and what the host
+was doing in them).
 
 The reduction reads the ``.xplane.pb`` file that ``jax.profiler`` writes,
 through ``jax.profiler.ProfileData`` and nothing else. On a TPU each chip is
 a plane ``/device:TPU:<n>`` whose line ``XLA Ops`` holds one event per
 operation run; the host's threads are lines of the plane ``/host:CPU``.
+An event's name is the operation's HLO text, ``%<name> = <shape>
+<opcode>(<operands>), ...``, or the bare name in a hand-written trace; the
+operation's own name is the part before `` = ``.
 """
 
 from __future__ import annotations
@@ -22,6 +26,36 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
 TOP = 10
+# HLO collectives; their asynchronous halves are ``<opcode>-start`` and
+# ``<opcode>-done``, which these prefixes cover
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9-]*)\(")
+
+
+def own_name(op: str) -> str:
+    """``%tsl_rmsnorm.14 = bf16[16,1024]{...} custom-call(...)`` ->
+    ``tsl_rmsnorm.14``."""
+    return op.split(" = ", 1)[0].lstrip("%")
+
+
+def is_kernel(op: str, name: str) -> bool:
+    """Whether ``op`` is a call of the kernel ``name``: the kernel's name
+    begins the op's own name (``tsl_paged_attention.9``). A fusion that
+    merely reads the kernel's output, and so names it among its operands,
+    is not a call."""
+    return own_name(op).startswith(name)
+
+
+def is_collective(op: str) -> bool:
+    """Whether ``op`` is a collective: its own name or its HLO opcode
+    begins with one of COLLECTIVES."""
+    head, _, text = op.partition(" = ")
+    words = [head.lstrip("%")]
+    m = _OPCODE.search(text)
+    if m:
+        words.append(m.group(1))
+    return any(w.startswith(COLLECTIVES) for w in words)
 
 
 class Tracer:
@@ -71,17 +105,19 @@ class Summary:
     window_s: float                     # traced window, host clock
     devices: int                        # device planes seen
     busy_s: float                       # union of op intervals, mean/device
+    collective_s: float = 0.0           # collective ops' seconds, mean/device
     op_s: dict = field(default_factory=dict)       # op name -> seconds (all)
     op_count: dict = field(default_factory=dict)   # op name -> events (all)
     idle_gaps: list = field(default_factory=list)  # [(label, seconds)]
 
     def kernel_s(self, name: str) -> float:
-        """Seconds of every device op whose name contains ``name``, summed
-        over devices."""
-        return sum(s for op, s in self.op_s.items() if name in op)
+        """Seconds of every call of the kernel ``name`` (``is_kernel``),
+        summed over devices."""
+        return sum(s for op, s in self.op_s.items() if is_kernel(op, name))
 
     def kernel_count(self, name: str) -> int:
-        return sum(c for op, c in self.op_count.items() if name in op)
+        return sum(c for op, c in self.op_count.items()
+                   if is_kernel(op, name))
 
     def top_ops(self, n: int = TOP) -> list:
         return sorted(self.op_s.items(), key=lambda kv: -kv[1])[:n]
@@ -125,7 +161,7 @@ def reduce(path: Path, window_s: float) -> Summary:
         data = jax.profiler.ProfileData.from_file(str(path))
     op_s: dict = defaultdict(float)
     op_count: dict = defaultdict(int)
-    busy, devices = 0, 0
+    busy, devices, collective = 0, 0, 0
     first_device_intervals = None
     host_events = []
     for plane in data.planes:
@@ -147,6 +183,8 @@ def reduce(path: Path, window_s: float) -> Summary:
                 intervals.append((s, s + d))
                 op_s[ev.name] += d * 1e-9
                 op_count[ev.name] += 1
+                if is_collective(ev.name):
+                    collective += d
             busy += union_length(intervals)
             if first_device_intervals is None:
                 first_device_intervals = intervals
@@ -157,7 +195,9 @@ def reduce(path: Path, window_s: float) -> Summary:
         idle = [(_host_label(host_events, s, e), (e - s) * 1e-9)
                 for s, e in holes]
     return Summary(window_s=window_s, devices=devices,
-                   busy_s=busy * 1e-9 / max(devices, 1), op_s=dict(op_s),
+                   busy_s=busy * 1e-9 / max(devices, 1),
+                   collective_s=collective * 1e-9 / max(devices, 1),
+                   op_s=dict(op_s),
                    op_count=dict(op_count), idle_gaps=idle)
 
 

@@ -110,13 +110,19 @@ def _leaf(key, path: str, shape, dtype, vocab: int):
     return x.astype(dtype)
 
 
-def make_params(dims: Dims, seed: int, dtype=None) -> dict:
-    """The nested parameter tree for ``seed``, made on the default device in
-    one jitted call (``dtype`` overrides the configuration's)."""
+def make_params(dims: Dims, seed: int, dtype=None, shardings=None) -> dict:
+    """The nested parameter tree for ``seed``, made in one jitted call
+    (``dtype`` overrides the configuration's). By default it is made on the
+    default device; ``shardings``, a tree of the same structure, places
+    each leaf as the program places its own, and each chip then draws only
+    its shard. The values are the same either way: a leaf depends on the
+    seed and its path alone, and the threefry generator is partitionable."""
     shapes = param_shapes(dims)
 
     def gen(key):
         return nest({p: _leaf(key, p, s, dtype or t, dims.vocab)
                      for p, (s, t) in shapes.items()})
 
-    return jax.jit(gen)(seed_key(seed))
+    if shardings is None:
+        return jax.jit(gen)(seed_key(seed))
+    return jax.jit(gen, out_shardings=shardings)(seed_key(seed))

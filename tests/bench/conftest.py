@@ -30,6 +30,19 @@ def tiny_config(dtype="float32"):
     return cfg
 
 
+def tiny_qwen3_config(dtype="float32", mesh=None):
+    """The tiny configuration with Qwen3's shape of block: GQA (8 query
+    heads on 4 KV heads), per-head q/k RMSNorm, no biases, an untied head;
+    ``mesh`` = (data, model) adds a serving mesh."""
+    cfg = tiny_config(dtype)
+    cfg.update(arch="qwen3-14b", num_attention_heads=8,
+               num_key_value_heads=4, head_dim=16, qkv_bias=False,
+               qk_norm=True, tie_word_embeddings=False)
+    if mesh is not None:
+        cfg["serve"]["mesh"] = {"data": mesh[0], "model": mesh[1]}
+    return cfg
+
+
 def tiny_mix():
     from bench import spec
 

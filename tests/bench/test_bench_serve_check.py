@@ -83,6 +83,8 @@ def test_a_whole_run_is_correct_and_reports_its_metrics(tiny_ctx,
     assert all(v["value"] > 0 for v in res["metrics"].values())
     assert list(res)[-1] == "check"
     assert res["counters"]["window_compiles"] == 0
+    # the configuration names no mesh: the engine runs unmeshed
+    assert "mesh" not in res["counters"]
 
 
 def _altered_sampler(orig):
@@ -131,8 +133,9 @@ def test_a_traced_run_reports_the_trace_window(tiny_ctx, bind_library,
     """--trace 1: the profiler runs inside the window from a thread of its
     own; the result carries the traced window and a breakdown, and the raw
     trace is kept where asked. The CPU has no TPU plane, so the cell's
-    device metric reads nothing (its metrics from the requests read), and a
-    listed per-layer metric that reads nothing makes the run not correct."""
+    device-trace metrics read nothing (its metrics from the requests, the
+    program's counters and its spans read), and a listed per-layer metric
+    that reads nothing makes the run not correct."""
     from bench import trace
 
     bind_library("cpu_xla")
@@ -145,9 +148,11 @@ def test_a_traced_run_reports_the_trace_window(tiny_ctx, bind_library,
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
     listed = spec.cell_metrics(spec.benchmark(), "qwen1.5-0.5b.chat",
                                "per_layer")
-    assert set(res["metrics"]) == {m["name"] for m in listed
-                                   if m["source"] != "device_trace"}
-    assert res["check"]["metrics_missing"]["value"] == 1
+    on_device = {m["name"] for m in listed if m["source"] == "device_trace"}
+    assert {"device_idle.chat", "host_gap_ms.chat",
+            "decode_step_ms.chat"} <= on_device
+    assert set(res["metrics"]) == {m["name"] for m in listed} - on_device
+    assert res["check"]["metrics_missing"]["value"] == len(on_device)
     assert not res["correct"]
     assert all(c["ok"] for n, c in res["check"].items()
                if n != "metrics_missing")

@@ -5,6 +5,7 @@ with known values."""
 import gzip
 import math
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import pytest
@@ -126,6 +127,9 @@ def test_programs_and_the_step_gap(spans_file):
     assert {"rid": "r1", "chunk": 0} in [a for n, _, _, a in s.spans]
     assert host_gap_ms.read("host_gap_ms.chat", {"spans": s}) == \
         pytest.approx(4500e-6)
+    # two runs of the decode program, 7000 ns on the device
+    assert decode_step_ms.read("decode_step_ms.chat", {"spans": s}) == \
+        pytest.approx(3500e-6)
 
 
 def test_a_trace_without_a_device_plane_reads_nothing(tmp_path):
@@ -135,11 +139,13 @@ def test_a_trace_without_a_device_plane_reads_nothing(tmp_path):
     path.write_bytes(raw)
     assert bspans.reduce_spans(path) is None       # no serve.step span
     assert host_gap_ms.read("host_gap_ms.chat", {"spans": None}) is None
+    assert decode_step_ms.read("decode_step_ms.chat", {"spans": None}) is None
 
 
 def _record():
     """Three requests in a 10-s window (one never served) and one after it,
-    with the program's stamps; 6 slots, 10 decode steps."""
+    with the program's stamps; 6 slots, 10 decode steps, 8 of them in the
+    traced window."""
     reqs = [
         {"rid": "a", "arrival_s": 1.0, "tokens_out": 4, "ttft_s": 0.5,
          "latency_s": 2.5, "queue_s": 0.2, "park_s": 1.0,
@@ -155,7 +161,10 @@ def _record():
     return {"seconds": 10.0, "requests": reqs, "slots": 6,
             "decode_steps": 10, "decode_slot_steps": 45,
             "phases": {"decode": {"n": 8, "s": 0.96},
-                       "step": {"n": 10, "s": 1.5}}}
+                       "step": {"n": 10, "s": 1.5}},
+            "spans": SimpleNamespace(host_gap_ms=4.5, program_s={
+                "jit_serve_chunk": {"n": 3, "s": 0.3},
+                "jit_serve_decode_paged": {"n": 8, "s": 0.48}})}
 
 
 def test_the_readers_on_a_record_with_known_values():
@@ -169,8 +178,10 @@ def test_the_readers_on_a_record_with_known_values():
     assert itl_p90_ms.read("itl_p90_ms.chat", rec) == pytest.approx(1200)
     assert decode_occupancy.read("decode_occupancy.chat", rec) == \
         pytest.approx(75.0)
+    # the decode program's device time, not the chunk program's
     assert decode_step_ms.read("decode_step_ms.chat", rec) == \
-        pytest.approx(120.0)
+        pytest.approx(60.0)
+    assert host_gap_ms.read("host_gap_ms.chat", rec) == 4.5
 
 
 def test_the_readers_read_nothing_from_a_program_without_stamps():
@@ -180,7 +191,8 @@ def test_the_readers_read_nothing_from_a_program_without_stamps():
     for r in rec["requests"]:
         for k in ("queue_s", "park_s", "token_s"):
             r.pop(k, None)
-    for k in ("slots", "decode_steps", "decode_slot_steps", "phases"):
+    for k in ("slots", "decode_steps", "decode_slot_steps", "phases",
+              "spans"):
         rec.pop(k)
     for reader in (queue_wait_p50_s, park_share, itl_p90_ms,
                    decode_occupancy, decode_step_ms, host_gap_ms):
@@ -231,6 +243,8 @@ def test_the_chip_trace_names_every_engine_program(chip):
         assert chip.program_s[p]["n"] > 0
     dec = chip.program_s["jit_serve_decode_paged"]
     assert 0.05 < dec["s"] / dec["n"] < 0.5         # a decode step's seconds
+    assert decode_step_ms.read("decode_step_ms.chat", {"spans": chip}) == \
+        pytest.approx(1e3 * dec["s"] / dec["n"])
 
 
 def test_the_chip_trace_idle_falls_inside_steps(chip):

@@ -38,6 +38,18 @@ def test_cell_files_are_found_by_name(cell):
     assert w["chips"] in (1, 4) and len(w["why"]) <= 200
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_configurations_mesh_covers_its_cells_chips(cell):
+    """A configuration that names a serving mesh spans exactly the chips of
+    every cell that runs it; one that names none runs unmeshed."""
+    w = spec.cell(BENCH, cell)
+    config = spec.config_file(BENCH, w["config"])
+    mesh = config["serve"].get("mesh")
+    # mesh_shape raises where the mesh misses the cell's chips
+    assert spec.mesh_shape(config, w["chips"]) == (
+        None if mesh is None else (mesh["data"], mesh["model"]))
+
+
 @pytest.mark.parametrize("metric", [m["name"] for m in METRICS])
 def test_metric_readers_are_found_by_name(metric):
     mod = importlib.import_module(f"bench.metrics.{metric.split('.')[0]}")
