@@ -30,6 +30,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import rows as _rows
+
 _NEG_INF = -1e30
 
 
@@ -61,15 +63,52 @@ def page_write(pool, rows, table, *, page: int):
     return pool.at[idx].set(rows.astype(pool.dtype))
 
 
-def paged_attention_ref(q, k_pool, v_pool, tables, kv_len, *, k_scale=None,
-                        v_scale=None, scale=None, pages_per_step: int = 1):
+def token_write(pool, tokens, pages, offs, *, layer=None):
+    """``pool`` with ``tokens`` (B, KH, C, d) written as token ``offs[b, c]``
+    of page ``pages[b, c]``, in the row format of ``rows.py``; ``pool`` is
+    one layer's (KH, n_pages, rows, w) or, with ``layer``, a stack
+    (L, ..., KH, n_pages, rows, w) written at that layer. One
+    dynamic-update-slice per token, in a loop whose body is one write at
+    any batch and span."""
+    b, kh, c, d = tokens.shape
+    lead = () if layer is None else jnp.unravel_index(
+        jnp.asarray(layer, jnp.int32), pool.shape[:-4])
+    flat = jnp.swapaxes(tokens, 1, 2).reshape(b * c, kh, d)
+    pid = jnp.asarray(pages, jnp.int32).reshape(-1)
+    off = jnp.asarray(offs, jnp.int32).reshape(-1)
+
+    def one(t, pool):
+        return _rows.write_token(pool, flat[t], lead, pid[t], off[t])
+
+    return jax.lax.fori_loop(0, b * c, one, pool)
+
+
+def stack_layers(*pools, layer=None):
+    """``pools`` as ``(L, KH, n_pages, rows, W)`` stacks and the int32 layer
+    to read: a single layer's ``(KH, n_pages, rows, W)`` pool is a stack of
+    one (``layer`` None reads it); a ``(L, ..., KH, n_pages, rows, W)``
+    stack flattens its leading axes into the layer index. Reshapes only."""
+    pools = [jnp.asarray(p) for p in pools]
+    if layer is None:
+        return (*(p[None] for p in pools), jnp.int32(0))
+    return (*(p.reshape((-1,) + p.shape[-4:]) for p in pools),
+            jnp.asarray(layer, jnp.int32))
+
+
+def paged_attention_ref(q, k_pool, v_pool, tables, kv_len, *, layer=None,
+                        k_scale=None, v_scale=None, scale=None,
+                        pages_per_step: int = 1):
     """Gather-free paged attention: decode/verify straight off the page pool.
 
     q: (B, H, SQ, D) — SQ = 1 for decode, the verify span width otherwise.
     k_pool/v_pool: (KH, n_pages, page, D) — the WHOLE pool, every resident
-    request's pages interleaved. tables: (B, P) int32 PAGE IDS (indices into
-    the pool's page axis — not the start-row offsets ``page_read`` takes:
-    the block table never leaves page-id space here, which is the point).
+    request's pages interleaved; a pool may pack f consecutive tokens per
+    row, (KH, n_pages, page // f, w) (the format of ``rows.py``) — or, with
+    ``layer``, a stack of such pools (L, ..., KH, n_pages, ·, ·) read at
+    that layer page by page (the layer is never sliced out first).
+    tables: (B, P) int32 PAGE IDS (indices into the pool's page axis —
+    not the start-row offsets ``page_read`` takes: the block table never
+    leaves page-id space here, which is the point).
     kv_len: (B,) int32 rows written per sequence; rows of page p beyond it
     are masked, and table entries past the covered range must still be
     *valid* page ids (a scratch page) — they are fetched, then masked.
@@ -87,7 +126,11 @@ def paged_attention_ref(q, k_pool, v_pool, tables, kv_len, *, k_scale=None,
     past every query row and mask out.
     """
     b, h, sq, d = q.shape
-    kh, _, page, _ = k_pool.shape
+    kh = k_pool.shape[-4]
+    page = _rows.page_tokens(k_pool, d)
+    if k_scale is not None:
+        k_scale, v_scale, _ = stack_layers(k_scale, v_scale, layer=layer)
+    k_pool, v_pool, layer = stack_layers(k_pool, v_pool, layer=layer)
     group = h // kh
     n_p = tables.shape[1]
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -112,13 +155,18 @@ def paged_attention_ref(q, k_pool, v_pool, tables, kv_len, *, k_scale=None,
     def step(carry, xs):
         m, l, acc = carry
         p, pid = xs                                          # (), (B, G)
-        k = jnp.take(k_pool, pid, axis=1)                # (KH, B, G, page, D)
-        v = jnp.take(v_pool, pid, axis=1)
+
+        def pages(pool, width):
+            # (B, G, KH, page, width): the table's pages of this layer, rows
+            # that pack several tokens unpacked
+            return _rows.unpack(pool[layer, :, pid], width)
+
+        k, v = pages(k_pool, d), pages(v_pool, d)
         if k_scale is not None:
-            k = k.astype(jnp.float32) * jnp.take(k_scale, pid, axis=1)
-            v = v.astype(jnp.float32) * jnp.take(v_scale, pid, axis=1)
-        k = jnp.moveaxis(k, 1, 0).astype(jnp.float32)
-        v = jnp.moveaxis(v, 1, 0).astype(jnp.float32)
+            k = k.astype(jnp.float32) * pages(k_scale, 1)
+            v = v.astype(jnp.float32) * pages(v_scale, 1)
+        k = jnp.moveaxis(k, 2, 1).astype(jnp.float32)
+        v = jnp.moveaxis(v, 2, 1).astype(jnp.float32)
         k = k.reshape(b, kh, width, d)                   # (B, KH, G*page, D)
         v = v.reshape(b, kh, width, d)
         s = jnp.einsum("bkrd,bkcd->bkrc", qf, k) * sc

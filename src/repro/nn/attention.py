@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged import rows as paged_rows
 from repro.tsl_api import ops as tsl
 
 from .common import dense_init, split_keys
@@ -171,30 +172,39 @@ def _span_attend(p, x, k_cache, v_cache, pos, cfg, span_op):
     return tsl.matmul(o, p["wo"]), k_cache, v_cache
 
 
-def attention_span_paged(p, x, k_pool, v_pool, tables, pos, cfg, span_op, *,
-                         k_scale=None, v_scale=None):
+def attention_span_paged(p, x, pools, layer, tables, pos, cfg, span_op, *,
+                         names=("k", "v")):
     """Fused paged decode/verify span: project a span of C tokens per slot,
     write each row STRAIGHT into its block-table page, and attend directly
     against the page pool — no page->lane gather anywhere.
 
-    x: (B, C, D) span activations (C == 1 is the decode step); pools
-    (KH, n_pages, page, hd) — one layer's slice of the serve-layer pool;
-    tables (B, P) int32 page ids; ``pos`` scalar or (B,) per-slot base write
-    positions. ``span_op`` is ``tsl.attention_decode_paged`` (C == 1) or
-    ``tsl.attention_verify_paged``; both mask ends-aligned at kv_len =
-    pos + C, so rows beyond a slot's committed fill are dead — rollback
-    stays free exactly as in the lane path. ``k_scale``/``v_scale``
-    (KH, n_pages, page, 1) switch the pools to the absmax-int8 wire format:
-    rows quantize per write and dequantize per touched page inside the
-    primitive. Inactive slots must point at a scratch page (valid id):
-    their row writes and reads land there harmlessly.
+    x: (B, C, D) span activations (C == 1 is the decode step); ``pools`` the
+    serve-layer pool dict, whose ``names`` leaves are this attention's K and
+    V pools stacked over layers, (L, ..., KH, n_pages, page // f, w) in the
+    packed row format of ``kernels/paged/rows.py``; ``layer`` (traced
+    int32) the flat index of this layer in the stack's leading axes; tables
+    (B, P) int32 page ids; ``pos`` scalar or (B,) per-slot base write
+    positions. The rows land at [layer, :, page, row] in place
+    (``cache_token_write_paged``) and the attention primitive reads the
+    stack at ``layer``: no layer slice is taken, so a caller that
+    carries the pools through its layer loop updates them in place.
+    ``span_op`` is ``tsl.attention_decode_paged`` (C == 1) or
+    ``tsl.attention_verify_paged``; both mask ends-aligned at kv_len = pos +
+    C, so rows beyond a slot's committed fill are dead — rollback stays free
+    exactly as in the lane path. ``{name}__scale`` pools (..., page, 1)
+    switch a leaf to the absmax-int8 wire format: rows quantize per write
+    and dequantize per touched page inside the primitive. Inactive slots
+    must point at a scratch page (valid id): their row writes and reads land
+    there harmlessly.
 
-    Returns (y (B,C,D), k_pool', v_pool', k_scale', v_scale')."""
+    Returns (y (B,C,D), pools')."""
     from repro.dist.compression import quantize_absmax_int8
 
     b, c, _ = x.shape
     h, hd = cfg.n_heads, cfg.hd
-    page = k_pool.shape[-2]
+    kn, vn = names
+    pool_k = pools[kn]
+    page = paged_rows.page_tokens(pool_k, hd)
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (b,))
@@ -203,24 +213,19 @@ def attention_span_paged(p, x, k_pool, v_pool, tables, pos, cfg, span_op, *,
     tab = jnp.asarray(tables, jnp.int32)
     pid = jnp.take_along_axis(tab, positions // page, axis=1)  # (B, C)
     off = positions % page
-    # pool.at[:, pid, off] broadcasts the (B, C) index pair under the KH
-    # slice -> (KH, B, C, hd) update slabs, heads-major like the pool
-    kr = jnp.swapaxes(k, 0, 1)
-    vr = jnp.swapaxes(v, 0, 1)
-    if k_scale is not None:
-        qk, sk = quantize_absmax_int8(kr)
-        qv, sv = quantize_absmax_int8(vr)
-        k_pool = k_pool.at[:, pid, off].set(qk)
-        v_pool = v_pool.at[:, pid, off].set(qv)
-        k_scale = k_scale.at[:, pid, off].set(sk)
-        v_scale = v_scale.at[:, pid, off].set(sv)
-    else:
-        k_pool = k_pool.at[:, pid, off].set(kr.astype(k_pool.dtype))
-        v_pool = v_pool.at[:, pid, off].set(vr.astype(v_pool.dtype))
-    o = span_op(q, k_pool, v_pool, tab, kv_len=pos + c,
-                k_scale=k_scale, v_scale=v_scale)
+    pools = dict(pools)
+    for name, rows in ((kn, k), (vn, v)):
+        if f"{name}__scale" in pools:
+            rows, sc = quantize_absmax_int8(rows)
+            pools[f"{name}__scale"] = tsl.cache_token_write_paged(
+                pools[f"{name}__scale"], sc, pid, off, layer=layer)
+        pools[name] = tsl.cache_token_write_paged(pools[name], rows, pid,
+                                                  off, layer=layer)
+    o = span_op(q, pools[kn], pools[vn], tab, kv_len=pos + c, layer=layer,
+                k_scale=pools.get(f"{kn}__scale"),
+                v_scale=pools.get(f"{vn}__scale"))
     o = o.transpose(0, 2, 1, 3).reshape(b, c, h * hd)
-    return tsl.matmul(o, p["wo"]), k_pool, v_pool, k_scale, v_scale
+    return tsl.matmul(o, p["wo"]), pools
 
 
 def attention_decode(p, x_t, k_cache, v_cache, pos, cfg, *, rope: bool = True):

@@ -245,40 +245,28 @@ def encdec_verify_step(params, state, tokens, pos, cfg):
 def _encdec_paged_span(params, state, pools, tables, tokens, pos, cfg,
                        span_op):
     """Fused-paged decode/verify body: decoder self-attention writes and
-    reads its span straight against the page pools (attention_span_paged);
+    reads its span straight against the page pools (attention_span_paged),
+    carried whole through the layer scan and indexed by layer;
     cross-attention still runs against the per-request cross K/V TAILS in
     ``state`` (fixed-size, never paged). Returns (logits, pools)."""
     x = tsl.embed_lookup(params["embed"], tokens)
-    int8 = "k__scale" in pools
-    xs = [params["dec_blocks"], pools["k"], pools["v"],
-          state["cross_k"], state["cross_v"]]
-    if int8:
-        xs += [pools["k__scale"], pools["v__scale"]]
 
-    def body(x_c, inp):
-        if int8:
-            bp, kp, vp, ck, cv, ks, vs = inp
-            ks, vs = ks[0], vs[0]
-        else:
-            bp, kp, vp, ck, cv = inp
-            ks = vs = None
-        h, kp0, vp0, ks0, vs0 = attention_span_paged(
+    def body(carry, inp):
+        x_c, pl = carry
+        bp, ck, cv, layer = inp
+        h, pl = attention_span_paged(
             bp["self_attn"], apply_norm_params(cfg, bp["self_norm"], x_c),
-            kp[0], vp[0], tables, pos, cfg, span_op,
-            k_scale=ks, v_scale=vs)
+            pl, layer, tables, pos, cfg, span_op)
         x_c = x_c + h
         q_in = apply_norm_params(cfg, bp["cross_norm"], x_c)
         x_c = x_c + cross_attention_forward(bp["cross_attn"], q_in, ck, cv, cfg)
         x_c = x_c + mlp_forward(bp["mlp"], apply_norm_params(cfg, bp["mlp_norm"], x_c), cfg)
-        ys = (kp0[None], vp0[None])
-        if int8:
-            ys += (ks0[None], vs0[None])
-        return x_c, ys
+        return (x_c, pl), None
 
-    x, ys = _scan(body, x, tuple(xs))
-    pools = {**pools, "k": ys[0], "v": ys[1]}
-    if int8:
-        pools["k__scale"], pools["v__scale"] = ys[2], ys[3]
+    (x, pools), _ = _scan(
+        body, (x, pools),
+        (params["dec_blocks"], state["cross_k"], state["cross_v"],
+         jnp.arange(state["cross_k"].shape[0])))
     x = apply_norm_params(cfg, params["final_norm"], x)
     return tsl.matmul(x, params["head"]), pools
 

@@ -228,42 +228,30 @@ def lm_verify_step(params, state, tokens, pos, cfg):
 
 def _lm_paged_span(params, pools, tables, tokens, pos, cfg, span_op):
     """Shared fused-paged decode/verify body: every layer's span attends
-    DIRECTLY against its slice of the block-table page pools (see
-    attention_span_paged) — the per-layer pool slices ride the layer scan
-    as xs/ys exactly like the lane caches do, so HLO stays O(1) in depth.
-    Returns (logits (B,C,V), new pools dict)."""
+    DIRECTLY against the block-table page pools (see attention_span_paged).
+    The stacked pools ride the layer scan's CARRY, whole, and each layer
+    reads and writes them at its own index (the scan's counter): no layer
+    slice is taken or stacked back, so the loop updates the donated pools in
+    place and HLO stays O(1) in depth. Returns (logits (B,C,V), new pools
+    dict)."""
     x = tsl.embed_lookup(params["embed"], tokens)
-    int8 = "k__scale" in pools
-    xs = [params["blocks"], pools["k"], pools["v"]]
-    if int8:
-        xs += [pools["k__scale"], pools["v__scale"]]
 
-    def body(x_c, inp):
-        if int8:
-            bp, kp, vp, ks, vs = inp
-            ks, vs = ks[0], vs[0]
-        else:
-            bp, kp, vp = inp
-            ks = vs = None
-        h, kp0, vp0, ks0, vs0 = attention_span_paged(
+    def body(carry, inp):
+        x_c, pl = carry
+        bp, layer = inp
+        h, pl = attention_span_paged(
             bp["attn"], apply_norm_params(cfg, bp["attn_norm"], x_c),
-            kp[0], vp[0], tables, pos, cfg, span_op,
-            k_scale=ks, v_scale=vs)
+            pl, layer, tables, pos, cfg, span_op)
         x_c = x_c + h
         y = apply_norm_params(cfg, bp["mlp_norm"], x_c)
         if cfg.n_experts:
             y, _ = moe_forward(bp["moe"], y, cfg)
         else:
             y = mlp_forward(bp["mlp"], y, cfg)
-        ys = (kp0[None], vp0[None])
-        if int8:
-            ys += (ks0[None], vs0[None])
-        return x_c + y, ys
+        return (x_c + y, pl), None
 
-    x, ys = _scan(body, x, tuple(xs))
-    pools = {**pools, "k": ys[0], "v": ys[1]}
-    if int8:
-        pools["k__scale"], pools["v__scale"] = ys[2], ys[3]
+    (x, pools), _ = _scan(body, (x, pools),
+                          (params["blocks"], jnp.arange(cfg.n_layers)))
     x = apply_norm_params(cfg, params["final_norm"], x)
     return lm_head(params, x, cfg), pools
 

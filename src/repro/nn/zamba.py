@@ -206,13 +206,17 @@ def state_batch_axes(state):
     return {k: 2 if k in ("h", "conv") else 1 for k in state}
 
 
+# the shared attention block's K and V cache (and page-pool) leaves
+_ATTN = ("attn_k", "attn_v")
+
+
 def state_page_axes(state):
     """Token-axis per leaf for PAGED serving: only the shared-attention KV
     caches (G, B, KH, S, hd) grow per token (axis 3). The SSM/conv leaves
     are fixed-size recurrent state — ``None`` marks them as the per-request
     TAIL the paged store snapshots whole (and shares at prefix boundaries)
     instead of paging."""
-    return {k: 3 if k in ("attn_k", "attn_v") else None for k in state}
+    return {k: 3 if k in _ATTN else None for k in state}
 
 
 def _zamba_paged_chunk(params, state, pools, tables, tokens, pos, cfg, *,
@@ -220,10 +224,10 @@ def _zamba_paged_chunk(params, state, pools, tables, tokens, pos, cfg, *,
     """Fused-paged analogue of :func:`zamba_prefill_chunk`: the mamba layers
     carry the TAIL state (h, conv) exactly as before, while the shared
     attention block writes and reads its span straight against the page
-    pools (attention_span_paged) — the per-group pool slices ride the group
-    scan as xs/ys. Returns (logits, new tail state, new pools)."""
+    pools (attention_span_paged) — the stacked pools ride the group scan's
+    carry, read and written at the group's index. Returns (logits, new tail
+    state, new pools)."""
     x = tsl.embed_lookup(params["embed"], tokens)
-    int8 = "attn_k__scale" in pools
 
     def mamba_body(x_c, inp):
         bp, h0, conv_prev = inp
@@ -232,33 +236,21 @@ def _zamba_paged_chunk(params, state, pools, tables, tokens, pos, cfg, *,
             h0=h0, conv_prev=conv_prev, n_real=n_real)
         return x_c + y, (h_f, conv_tail)
 
-    def group_body(x_c, inp):
-        if int8:
-            gp, h_g, conv_g, kp, vp, ks, vs = inp
-            ks, vs = ks[0], vs[0]
-        else:
-            gp, h_g, conv_g, kp, vp = inp
-            ks = vs = None
+    def group_body(carry, inp):
+        x_c, pl = carry
+        gp, h_g, conv_g, group = inp
         x_c, (h_new, conv_new) = _scan(mamba_body, x_c, (gp, h_g, conv_g))
-        a, kp0, vp0, ks0, vs0 = attention_span_paged(
+        a, pl = attention_span_paged(
             params["shared_attn"],
             apply_norm_params(cfg, params["shared_attn_norm"], x_c),
-            kp[0], vp[0], tables, pos, cfg, span_op,
-            k_scale=ks, v_scale=vs)
-        ys = (h_new, conv_new, kp0[None], vp0[None])
-        if int8:
-            ys += (ks0[None], vs0[None])
-        return x_c + a, ys
+            pl, group, tables, pos, cfg, span_op, names=_ATTN)
+        return (x_c + a, pl), (h_new, conv_new)
 
-    xs = [params["groups"], state["h"], state["conv"],
-          pools["attn_k"], pools["attn_v"]]
-    if int8:
-        xs += [pools["attn_k__scale"], pools["attn_v__scale"]]
-    x, ys = _scan(group_body, x, tuple(xs))
-    new_state = {**state, "h": ys[0], "conv": ys[1]}
-    new_pools = {**pools, "attn_k": ys[2], "attn_v": ys[3]}
-    if int8:
-        new_pools["attn_k__scale"], new_pools["attn_v__scale"] = ys[4], ys[5]
+    (x, pools), (h, conv) = _scan(
+        group_body, (x, pools),
+        (params["groups"], state["h"], state["conv"],
+         jnp.arange(state["h"].shape[0])))
+    new_state = {**state, "h": h, "conv": conv}
     if "rest" in params:
         x, (h_r, conv_r) = _scan(
             mamba_body, x, (params["rest"], state["h_rest"],
@@ -266,16 +258,16 @@ def _zamba_paged_chunk(params, state, pools, tables, tokens, pos, cfg, *,
         new_state["h_rest"] = h_r
         new_state["conv_rest"] = conv_r
     x = apply_norm_params(cfg, params["final_norm"], x)
-    return lm_head(params, x, cfg), new_state, new_pools
+    return lm_head(params, x, cfg), new_state, pools
 
 
 def zamba_decode_step_paged(params, state, pools, tables, tokens_t, pos, cfg):
     """Fused paged decode: the mamba recurrence updates its TAIL state
     bit-identically to :func:`zamba_decode_step` (same mamba2_decode), and
-    the shared attention block decodes straight off the page pools.
-    Returns (logits (B,V), new tail state, new pools)."""
+    the shared attention block decodes straight off the page pools, carried
+    whole through the group scan. Returns (logits (B,V), new tail state, new
+    pools)."""
     x = tsl.embed_lookup(params["embed"], tokens_t)
-    int8 = "attn_k__scale" in pools
 
     def mamba_step(x_t, inp):
         bp, h, conv = inp
@@ -284,33 +276,22 @@ def zamba_decode_step_paged(params, state, pools, tables, tokens_t, pos, cfg):
                                    cfg, h, conv)
         return x_t + y, (h, conv)
 
-    def group_step(x_t, inp):
-        if int8:
-            gp, h_g, conv_g, kp, vp, ks, vs = inp
-            ks, vs = ks[0], vs[0]
-        else:
-            gp, h_g, conv_g, kp, vp = inp
-            ks = vs = None
+    def group_step(carry, inp):
+        x_t, pl = carry
+        gp, h_g, conv_g, group = inp
         x_t, (h_g, conv_g) = _scan(mamba_step, x_t, (gp, h_g, conv_g))
-        a, kp0, vp0, ks0, vs0 = attention_span_paged(
+        a, pl = attention_span_paged(
             params["shared_attn"],
             apply_norm_params(cfg, params["shared_attn_norm"], x_t),
-            kp[0], vp[0], tables, pos, cfg, tsl.attention_decode_paged,
-            k_scale=ks, v_scale=vs)
-        ys = (h_g, conv_g, kp0[None], vp0[None])
-        if int8:
-            ys += (ks0[None], vs0[None])
-        return x_t + a, ys
+            pl, group, tables, pos, cfg, tsl.attention_decode_paged,
+            names=_ATTN)
+        return (x_t + a, pl), (h_g, conv_g)
 
-    xs = [params["groups"], state["h"], state["conv"],
-          pools["attn_k"], pools["attn_v"]]
-    if int8:
-        xs += [pools["attn_k__scale"], pools["attn_v__scale"]]
-    x, ys = _scan(group_step, x, tuple(xs))
-    new_state = {**state, "h": ys[0], "conv": ys[1]}
-    new_pools = {**pools, "attn_k": ys[2], "attn_v": ys[3]}
-    if int8:
-        new_pools["attn_k__scale"], new_pools["attn_v__scale"] = ys[4], ys[5]
+    (x, pools), (h, conv) = _scan(
+        group_step, (x, pools),
+        (params["groups"], state["h"], state["conv"],
+         jnp.arange(state["h"].shape[0])))
+    new_state = {**state, "h": h, "conv": conv}
     if "rest" in params:
         x, (h_r, conv_r) = _scan(
             mamba_step, x, (params["rest"], state["h_rest"],
@@ -318,7 +299,7 @@ def zamba_decode_step_paged(params, state, pools, tables, tokens_t, pos, cfg):
         new_state["h_rest"] = h_r
         new_state["conv_rest"] = conv_r
     x = apply_norm_params(cfg, params["final_norm"], x)
-    return lm_head(params, x, cfg)[:, 0], new_state, new_pools
+    return lm_head(params, x, cfg)[:, 0], new_state, pools
 
 
 def zamba_verify_step_paged(params, state, pools, tables, tokens, pos, cfg):

@@ -60,6 +60,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.dist import sharding as dist_sharding
+from repro.kernels.common import heads_spec
 from repro.nn.model import build_model
 
 from .paging import PagedConfig, PagedKVStore, prefix_key, selected_page_size
@@ -257,11 +258,10 @@ class ServeEngine:
         self._table_width = 0
         if self._fused:
             self._table_width = -(-self._state_len // self._store.page)
-        # page pools shard like the slot state they mirror: the token axis
-        # was split into (n_pages, page), so the PAGE axis takes the model
-        # entry the sequence dim would have (divisibility-guarded), and the
-        # engine re-pins after any host-path pool mutation so fused steps
-        # always see the same input shardings they compiled against
+        # page pools shard where the kernels read them (fused: KV heads on
+        # ``model``; lane mode: the flat row axis), and the engine re-pins
+        # after any host-path pool mutation so fused steps always see the
+        # same input shardings they compiled against
         self._pool_shardings: dict | None = None
         if mesh is not None and self._store is not None:
             self._pool_shardings = self._pool_sharding_rules()
@@ -473,19 +473,24 @@ class ServeEngine:
                 for n, a in pools.items()}
 
     def _pool_sharding_rules(self) -> dict:
-        """NamedSharding per pool leaf (scale pools as ``{leaf}__scale``):
-        the page axis — the split token axis — takes the ``model`` entry the
-        sequence dim carries in the slot table, divisibility-guarded."""
+        """NamedSharding per pool leaf (scale pools as ``{leaf}__scale``).
+        A fused pool ``(..., KH, n_pages, rows, width)`` puts its KV-head axis
+        on ``model`` exactly where the paged kernel's ``heads_spec`` does,
+        so each layer's call finds its heads on its own shard and nothing
+        moves; a lane-mode flat ``(rows, *row)`` pool puts its row axis
+        there. Both divisibility-guarded."""
         st = self._store
         tp = dist_sharding.tp_size(self.mesh)
+        heads = heads_spec(self.mesh, 1, self.cfg.n_heads,
+                           self.cfg.n_kv_heads)[1]
+        n_rows = st.n_pages * st.page
         out = {}
-        for name, (ax, row_shape, _dt) in st.paged.items():
-            page_axis = ax if st.fused else 0
-            ndim = len(row_shape) + (2 if st.fused else 1)
-            n_along = st.n_pages if st.fused else st.n_pages * st.page
-            entries = [None] * ndim
-            if tp > 1 and n_along % tp == 0 and n_along >= tp:
-                entries[page_axis] = "model"
+        for name, pool in st.pools.items():
+            entries = [None] * pool.ndim
+            if st.fused:
+                entries[-4] = heads
+            elif tp > 1 and n_rows % tp == 0 and n_rows >= tp:
+                entries[0] = "model"
             spec = PartitionSpec(*entries)
             out[name] = NamedSharding(self.mesh, spec)
             if name in st.scale_pools:
@@ -1011,6 +1016,9 @@ class ServeEngine:
         slot_steps = 0              # per-slot phase-2 participations
         decode_steps = 0            # plain (k=0) decode steps
         verify_steps = 0            # speculative verify rounds
+        # fused decode/verify steps: their program reads the stacked pools
+        # at each layer's index (attention_span_paged)
+        layer_indexed = 0
         # fused decode steps: table pages the active slots' kv_len covers,
         # and the block table's pages (slots x width)
         live_pages = table_pages = 0
@@ -1129,6 +1137,7 @@ class ServeEngine:
                                             self.params, state, pools,
                                             tables, tokens, pos_vec)
                                     self._store.set_device_pools(pools)
+                                    layer_indexed += 1
                                 else:
                                     logits, state = self._decode(
                                         self.params, state, tokens, pos_vec)
@@ -1172,6 +1181,7 @@ class ServeEngine:
                                         self._verify_paged(
                                             self.params, state, pools,
                                             tables, span, pos_vec)
+                                    layer_indexed += 1
                                 else:
                                     logits, state = self._verify(
                                         self.params, state, span, pos_vec)
@@ -1433,6 +1443,9 @@ class ServeEngine:
                 "gather_bytes_eliminated_per_step":
                     self._gather_bytes_eliminated
                     / max(decode_steps + verify_steps, 1),
+                # fused decode/verify steps whose program reads the pools by
+                # layer index, never moving a layer slice
+                "layer_indexed_steps": layer_indexed,
             }
         if self.spec is not None:
             # accepted-token rate + mean accepted span, overall and by bucket

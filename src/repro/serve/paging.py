@@ -32,9 +32,11 @@ FUSED mode (``PagedConfig.fused``, the default when the family has paged
 leaves) removes the activation gather from the hot path entirely: pools keep
 the leaf's own layout with the token axis split in-place into
 ``(n_pages, page)`` (e.g. a ``(L, 1, KH, S, hd)`` KV leaf becomes a
-``(L, 1, KH, n_pages, page, hd)`` pool), so each layer's slice is directly
-the ``(KH, n_pages, page, hd)`` operand of the ``attention_decode_paged`` /
-``attention_verify_paged`` UPD primitives. KV-family slots then decode and
+``(L, 1, KH, n_pages, page, hd)`` pool, whose rows pack and pad tokens to
+whole 128-lane tiles (``kernels/paged/rows.py``: ``(L, 1, KH, n_pages,
+page // f, w)``), so the whole stack is directly the pool operand of the
+``attention_decode_paged`` / ``attention_verify_paged`` UPD primitives,
+read at each layer's index. KV-family slots then decode and
 verify straight off the pool through per-step block tables (a dedicated
 SCRATCH page absorbs table entries beyond a slot's coverage); lane
 activation survives only for recurrent tails and as an explicit fallback,
@@ -70,17 +72,20 @@ import numpy as np
 from repro.dist.compression import (dequantize_absmax_int8,
                                     quantize_absmax_int8)
 from repro.kernels.paged import ref as _pref
+from repro.kernels.paged import rows as _prows
 
 from .slots import PageAllocator, PagesExhausted, SlotPages
 
 
 def serve_page_write(pool, rows, pids, ax: int):
     """Write ``rows`` (``len(pids) * page`` rows) into pages ``pids`` of a
-    fused-layout pool whose token axis ``ax`` is split into (n_pages, page)."""
-    blocks = rows.astype(pool.dtype).reshape(
-        (pids.shape[0], pool.shape[ax + 1]) + rows.shape[1:])
+    fused-layout pool whose token axis ``ax`` is split into (n_pages, page),
+    in the pool's row format (``kernels/paged/rows.py``)."""
+    n = pids.shape[0]
+    blocks = jnp.moveaxis(rows.astype(pool.dtype).reshape(
+        (n, rows.shape[0] // n) + rows.shape[1:]), (0, 1), (ax, ax + 1))
     idx = (slice(None),) * ax + (pids,)
-    return pool.at[idx].set(jnp.moveaxis(blocks, (0, 1), (ax, ax + 1)))
+    return pool.at[idx].set(_prows.pack(blocks, pool.shape[-1]))
 
 
 # the page-store write as one named program (``jit_serve_page_write``)
@@ -234,7 +239,7 @@ class PagedKVStore:
         self.paged: dict[str, tuple[int, tuple, object]] = {}
         self.tail_leaves: dict[str, tuple[tuple, object]] = {}
         tail_bytes = 0
-        row_bytes = 0
+        page_bytes = 0
         fp_row_bytes = 0
         for name, sd in donor_shapes.items():
             ax = page_axes.get(name)
@@ -246,16 +251,21 @@ class PagedKVStore:
             self.paged[name] = (int(ax), row_shape, sd.dtype)
             n_elem = int(np.prod(row_shape))
             fp_row_bytes += n_elem * sd.dtype.itemsize
+            lead = int(np.prod(row_shape[:-1]))
+            # elements a page stores: a fused pool's packed rows fill
+            # whole 128-lane tiles
+            stored = n_elem * self.page
+            if fused and ax == len(row_shape) - 1:
+                f, w = _prows.row_format(self.page, row_shape[-1])
+                stored = lead * (self.page // f) * w
             if self.int8:
                 # int8 payload + one f32 scale per last-axis row
-                row_bytes += n_elem + 4 * int(np.prod(row_shape[:-1]))
+                page_bytes += stored + 4 * lead * self.page
             else:
-                row_bytes += n_elem * sd.dtype.itemsize
-        self.row_bytes = row_bytes
+                page_bytes += stored * sd.dtype.itemsize
         self.fp_row_bytes = fp_row_bytes
         self.tail_bytes = tail_bytes
-        self.page_bytes = self.page * row_bytes if row_bytes \
-            else max(tail_bytes, 1)
+        self.page_bytes = page_bytes or max(tail_bytes, 1)
         if n_pages is None:
             if hbm_budget_bytes is None:
                 raise ValueError("pass hbm_budget_bytes or n_pages")
@@ -273,10 +283,15 @@ class PagedKVStore:
         for name, (ax, row_shape, dt) in self.paged.items():
             if self.fused:
                 # keep the leaf's own layout, token axis split in-place into
-                # (n_pages, page): directly the fused primitives' pool operand
+                # (n_pages, page): directly the fused primitives' pool
+                # operand; a token axis just before the last one takes the
+                # packed row format, so the pool fills whole lanes
                 shape = row_shape[:ax] + (self.n_pages, self.page) \
                     + row_shape[ax:]
                 sshape = shape[:-1] + (1,)
+                if ax == len(row_shape) - 1:
+                    f, w = _prows.row_format(self.page, row_shape[-1])
+                    shape = shape[:-2] + (self.page // f, w)
             else:
                 shape = (cap,) + row_shape
                 sshape = (cap,) + row_shape[:-1] + (1,)
@@ -330,18 +345,29 @@ class PagedKVStore:
             return ops.cache_page_write(pool, rows, off)
         return _pref.page_write(pool, rows, off, page=self.page)
 
-    def _pool_gather(self, pool, ax, pids):
-        """(len(pids)*page, *row) rows for page ids ``pids``, either layout."""
+    def _pool_gather(self, name, pids, *, scale: bool = False):
+        """(len(pids)*page, *row) rows of leaf ``name``'s pool (its scale
+        pool with ``scale``) for page ids ``pids``, either layout."""
+        ax, row_shape, _ = self.paged[name]
+        pool = (self.scale_pools if scale else self.pools)[name]
         if self.fused:
             idx = (slice(None),) * ax + (jnp.asarray(pids, jnp.int32),)
-            g = jnp.moveaxis(pool[idx], (ax, ax + 1), (0, 1))
+            g = _prows.unpack(pool[idx], 1 if scale else row_shape[-1])
+            g = jnp.moveaxis(g, (ax, ax + 1), (0, 1))
             return g.reshape((len(pids) * self.page,) + g.shape[2:])
         return self._gather(pool, self._offsets(pids))
 
-    def _pool_scatter(self, pool, ax, rows, pids):
+    def _pool_scatter(self, name, rows, pids, *, scale: bool = False):
+        """Write ``rows`` into pages ``pids`` of leaf ``name``'s pool (its
+        scale pool with ``scale``)."""
+        pools = self.scale_pools if scale else self.pools
         if self.fused:
-            return _page_write(pool, rows, np.asarray(pids, np.int32), ax)
-        return self._scatter(pool, rows, self._offsets(pids))
+            pools[name] = _page_write(pools[name], rows,
+                                      np.asarray(pids, np.int32),
+                                      self.paged[name][0])
+        else:
+            pools[name] = self._scatter(pools[name], rows,
+                                        self._offsets(pids))
 
     # -- accounting (the admission/"budget" interface) -----------------------
 
@@ -513,12 +539,10 @@ class PagedKVStore:
         pid = sp.pages[i]
         host: dict[str, np.ndarray] = {}
         for name in self.pools:
-            ax = self.paged[name][0]
-            host[name] = np.asarray(self._pool_gather(self.pools[name], ax,
-                                                      [pid]))
+            host[name] = np.asarray(self._pool_gather(name, [pid]))
             if self.int8:
                 host[f"{name}__scale"] = np.asarray(
-                    self._pool_gather(self.scale_pools[name], ax, [pid]))
+                    self._pool_gather(name, [pid], scale=True))
         self._spilled.setdefault(rid, {})[i] = host
         sp.pages[i] = -1
         self.allocator.release(pid)
@@ -540,14 +564,11 @@ class PagedKVStore:
                 host = spilled[i]
                 fresh = self._alloc_page()
                 for name in self.pools:
-                    ax = self.paged[name][0]
-                    self.pools[name] = self._pool_scatter(
-                        self.pools[name], ax, jnp.asarray(host[name]),
-                        [fresh])
+                    self._pool_scatter(name, jnp.asarray(host[name]), [fresh])
                     if self.int8:
-                        self.scale_pools[name] = self._pool_scatter(
-                            self.scale_pools[name], ax,
-                            jnp.asarray(host[f"{name}__scale"]), [fresh])
+                        self._pool_scatter(
+                            name, jnp.asarray(host[f"{name}__scale"]),
+                            [fresh], scale=True)
                 sp.pages[i] = fresh
                 self.rehydrates += 1
             del self._spilled[rid]
@@ -568,15 +589,12 @@ class PagedKVStore:
                 continue
             fresh = self._alloc_page()
             for name in self.pools:
-                ax = self.paged[name][0]
-                rows = self._pool_gather(self.pools[name], ax, [pid])
-                self.pools[name] = self._pool_scatter(self.pools[name], ax,
-                                                      rows, [fresh])
+                self._pool_scatter(name, self._pool_gather(name, [pid]),
+                                   [fresh])
                 if self.int8:
-                    srows = self._pool_gather(self.scale_pools[name], ax,
-                                              [pid])
-                    self.scale_pools[name] = self._pool_scatter(
-                        self.scale_pools[name], ax, srows, [fresh])
+                    self._pool_scatter(
+                        name, self._pool_gather(name, [pid], scale=True),
+                        [fresh], scale=True)
             self.allocator.release(pid)
             sp.pages[i] = fresh
             sp.n_shared = min(sp.n_shared, i)
@@ -603,7 +621,6 @@ class PagedKVStore:
         pids = sp.pages[p0:p1]
         need = (p1 - p0) * self.page
         for name in self.pools:
-            ax = self.paged[name][0]
             rows = rows_by_leaf[name]
             if rows.shape[0] < need:
                 pad = jnp.zeros((need - rows.shape[0],) + rows.shape[1:],
@@ -611,13 +628,10 @@ class PagedKVStore:
                 rows = jnp.concatenate([rows, pad], axis=0)
             if self.int8:
                 q, scale = quantize_absmax_int8(rows)
-                self.pools[name] = self._pool_scatter(self.pools[name], ax,
-                                                      q, pids)
-                self.scale_pools[name] = self._pool_scatter(
-                    self.scale_pools[name], ax, scale, pids)
+                self._pool_scatter(name, q, pids)
+                self._pool_scatter(name, scale, pids, scale=True)
             else:
-                self.pools[name] = self._pool_scatter(self.pools[name], ax,
-                                                      rows, pids)
+                self._pool_scatter(name, rows, pids)
 
     def snapshot_tail(self, donor: dict) -> dict:
         """Host copies of the tail leaves (donation-safe: the donor buffer
@@ -659,12 +673,11 @@ class PagedKVStore:
             for name in want:
                 ax, _, dt = self.paged[name]
                 if self.int8:
-                    q = self._pool_gather(self.pools[name], ax, sp.pages)
-                    s = self._pool_gather(self.scale_pools[name], ax,
-                                          sp.pages)
+                    q = self._pool_gather(name, sp.pages)
+                    s = self._pool_gather(name, sp.pages, scale=True)
                     rows = dequantize_absmax_int8(q, s, dtype=dt)
                 else:
-                    rows = self._pool_gather(self.pools[name], ax, sp.pages)
+                    rows = self._pool_gather(name, sp.pages)
                 n_rows = out[name].shape[ax]
                 rows = rows[:min(sp.fill, n_rows)]
                 full = jnp.zeros((n_rows,) + rows.shape[1:], dt)

@@ -92,6 +92,136 @@ def test_paged_attention_sweep(h, kh, sq, vmem, block_k, dt, monkeypatch):
                                np.asarray(want, np.float32), **_tol(dt))
 
 
+@pytest.mark.parametrize("h,kh,sq,vmem", [
+    (4, 4, 1, None),         # MHA decode
+    (10, 2, 1, None),        # GQA, group 5
+    (4, 2, 4, None),         # verify span of 4, group 2
+    (4, 4, 1, 96 << 10),     # a VMEM budget that splits the heads
+])
+@pytest.mark.parametrize("block_k", [16, 64, 256])
+@pytest.mark.parametrize("fold,pad", [(1, 0), (2, 0), (1, 8)],
+                         ids=["plain", "two-a-row", "padded"])
+def test_paged_attention_reads_stacked_layer(h, kh, sq, vmem, block_k, fold,
+                                            pad, monkeypatch):
+    """A (L, 1, KH, n_pages, page // fold, fold * D + pad) stack of L = 3
+    layers read at ``layer=l`` gives exactly what layer l's own pool gives
+    with ``layer=None``, for plain pools, pools that pack two tokens a row
+    and pools whose rows carry padding lanes; both match the jnp reference
+    read the same way."""
+    from repro.kernels.paged import kernel, ops, ref
+
+    n_layers, page, n_p, n_pages, d = 3, 64, 6, 16, 16
+    w = fold * d + pad
+    dt = "float32"
+    if vmem is not None:
+        monkeypatch.setattr(kernel, "ROW_BLOCK_VMEM_BYTES", vmem)
+        jax.clear_caches()
+    assert (kernel.head_group(kh, page // fold, w, 4) < kh) == (
+        vmem is not None)
+    scratch = n_pages - 1
+    perm = RNG.permutation(scratch)
+    tables = np.full((3, n_p), scratch, np.int32)
+    tables[1, :2] = perm[:2]
+    tables[2] = perm[2:8]
+    kv_len = np.asarray([1, 2 * page - 3, n_p * page], np.int32)
+    q = _arr((3, h, sq, d), dt, -1, 1)
+    shape = (n_layers, 1, kh, n_pages, page // fold, w)
+    k_stack, v_stack = _arr(shape, dt, -1, 1), _arr(shape, dt, -1, 1)
+    try:
+        for layer in range(n_layers):
+            got = ops.paged_attention(q, k_stack, v_stack, tables, kv_len,
+                                      layer=jnp.int32(layer), block_k=block_k,
+                                      interpret=True)
+            one = ops.paged_attention(q, k_stack[layer, 0],
+                                      v_stack[layer, 0], tables, kv_len,
+                                      block_k=block_k, interpret=True)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+            want = ref.paged_attention_ref(q, k_stack, v_stack, tables,
+                                           kv_len, layer=layer)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       **_tol(dt))
+    finally:
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("fold", [1, 2])
+def test_paged_attention_int8_reads_stacked_layer(fold):
+    """The int8 route (the jnp reference, dequantizing per touched page)
+    reads a stacked pool and its stacked scale pool at ``layer`` exactly as
+    it reads that layer's own pools."""
+    from repro.kernels.paged import ops
+
+    page, d = 8, 16
+    shape = (3, 1, 2, 5, page // fold, fold * d)
+    k8 = jnp.asarray(RNG.integers(-127, 128, shape), jnp.int8)
+    v8 = jnp.asarray(RNG.integers(-127, 128, shape), jnp.int8)
+    sshape = (3, 1, 2, 5, page, 1)
+    ks = jnp.asarray(RNG.uniform(0.005, 0.02, sshape), jnp.float32)
+    vs = jnp.asarray(RNG.uniform(0.005, 0.02, sshape), jnp.float32)
+    q = _arr((2, 4, 1, d), "float32", -1, 1)
+    tables = np.asarray([[0, 3, 1], [4, 2, 2]], np.int32)
+    kv_len = np.asarray([19, 9], np.int32)
+    for layer in range(3):
+        got = ops.paged_attention(q, k8, v8, tables, kv_len, layer=layer,
+                                  k_scale=ks, v_scale=vs)
+        one = ops.paged_attention(q, k8[layer, 0], v8[layer, 0], tables,
+                                  kv_len, k_scale=ks[layer, 0],
+                                  v_scale=vs[layer, 0])
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+
+
+@pytest.mark.parametrize("d,dt", [(64, "bfloat16"), (48, "float32"),
+                                  (112, "bfloat16"), (128, "float32"),
+                                  (64, "int8")],
+                         ids=["hd64", "hd48", "hd112", "hd128", "int8"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["stack", "one-layer"])
+def test_paged_token_write_matches_ref(d, dt, stacked):
+    """The row-write kernel (interpret mode) writes exactly what the jnp
+    reference writes, in the serving layer's row format (two tokens a row
+    at hd 64, padding lanes at 48 and 112): spans of 5 crossing a page
+    boundary, a slot on the scratch page, each layer of a stack of 3; every
+    token reads back where the format puts it, every other element stays."""
+    from repro.kernels.paged import ref, rows, write
+
+    kh, page, n_pages, b, c, n_layers = 2, 32, 6, 3, 5, 3
+    f, w = rows.row_format(page, d)
+    if dt == "int8":
+        def gen(shape):
+            return jnp.asarray(RNG.integers(-127, 128, shape), jnp.int8)
+    else:
+        def gen(shape):
+            return _arr(shape, dt, -1, 1)
+    pool = gen((n_layers, 1, kh, n_pages, page // f, w))
+    tokens = gen((b, kh, c, d))
+    tables = np.asarray([[0, 1], [2, 3], [5, 5]], np.int32)
+    positions = np.asarray([3, page - 2, 0])[:, None] + np.arange(c)
+    pages = np.take_along_axis(tables, positions // page, 1)
+    offs = positions % page
+    for layer in range(n_layers) if stacked else [None]:
+        target = pool if stacked else pool[1, 0]
+        got = write.token_write(target, tokens, pages, offs, layer=layer,
+                                interpret=True)
+        want = ref.token_write(target, tokens, pages, offs, layer=layer)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        one = got[layer, 0] if stacked else got
+        back = np.asarray(rows.unpack(one, d), np.float32)
+        for i in range(b):
+            for j in range(c):
+                np.testing.assert_array_equal(
+                    back[:, pages[i, j], offs[i, j]],
+                    np.asarray(tokens[i, :, j], np.float32))
+        # nothing but the written tokens changed
+        mask = np.ones(one.shape, bool)
+        for i in range(b):
+            for j in range(c):
+                o = offs[i, j]
+                mask[:, pages[i, j], o // f, (o % f) * d:(o % f + 1) * d] = 0
+        before = pool[layer, 0] if stacked else pool[1, 0]
+        np.testing.assert_array_equal(np.asarray(one, np.float32)[mask],
+                                      np.asarray(before, np.float32)[mask])
+
+
 @pytest.mark.parametrize("n", [64, 1000, 4096, 100_000])
 @pytest.mark.parametrize("dt", ["float32", "int32"])
 def test_range_count_sweep(n, dt):

@@ -69,6 +69,9 @@ def test_fused_matches_lane_all_kv_families(arch, enc_len):
     got = _run(cfg, reqs, fused=True, enc_len=enc_len, max_len=max_len)
     assert got["outputs"] == want["outputs"]
     assert got["paged"]["fused"] and not want["paged"]["fused"]
+    # every fused decode step reads the stacked pools by layer index
+    assert got["paged"]["layer_indexed_steps"] == got["decode_steps"] > 0
+    assert want["paged"]["layer_indexed_steps"] == 0
     assert got["paged"]["lane_activations"] == 0
     assert got["paged"]["tail_restores"] > 0       # park -> reactivate ran
     assert got["paged"]["gather_bytes_eliminated"] > 0
@@ -96,10 +99,12 @@ def test_fused_decode_page_counters():
 
 def test_fused_sampled_matches_lane():
     """Sampled decoding (temperature + top-k) draws the SAME per-step keys:
-    the fused pool read must not change a single draw vs lane activation."""
+    the fused pool read must not change a single draw vs lane activation.
+    Both requests arrive at once, so neither engine's batching (and so its
+    key draws) depends on how fast the host runs."""
     cfg = get_config("qwen1.5-0.5b").reduced()
     samp = SamplingConfig(temperature=0.8, top_k=16)
-    reqs = _requests(cfg, [6, 5], seed=2)
+    reqs = _requests(cfg, [6, 5], seed=2, stagger=0.0)
     want = _run(cfg, reqs, fused=False, sampling=samp, prefix_sharing=False)
     got = _run(cfg, reqs, fused=True, sampling=samp, prefix_sharing=False)
     assert got["outputs"] == want["outputs"]
@@ -131,6 +136,7 @@ def test_fused_speculative_matches_lane():
         got = _run(cfg, reqs, fused=True, max_len=32, speculation=spec)
         assert got["outputs"] == want["outputs"], arch
         assert got["spec"]["verify_steps"] > 0
+        assert got["paged"]["layer_indexed_steps"] == got["decode_steps"]
 
 
 def test_spill_rehydrate_exact_tokens():
@@ -193,3 +199,46 @@ def test_int8_fused_pool_absmax_bound():
     err = np.abs(deq - want)
     bound = np.abs(want).max(-1, keepdims=True) / 254.0 + 1e-7
     assert (err <= bound).all()
+
+
+def _scan_operands(jaxpr):
+    """(carry invars, xs invars, ys outvars) of every scan, nested ones
+    included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            n_c, n_k = eqn.params["num_consts"], eqn.params["num_carry"]
+            out.append((eqn.invars[n_c:n_c + n_k], eqn.invars[n_c + n_k:],
+                        eqn.outvars[n_k:]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _scan_operands(sub)
+    return out
+
+
+@pytest.mark.parametrize("arch,enc_len", [("qwen1.5-0.5b", None),
+                                          ("zamba2-7b", None),
+                                          ("whisper-tiny", 8)])
+def test_fused_decode_carries_pools_through_layer_scan(arch, enc_len):
+    """In each family's fused paged decode program the stacked pool leaves
+    enter the layer scan as carries and never as scan xs or ys: no layer's
+    slice of a pool is taken out or stacked back."""
+    cfg = get_config(arch).reduced()
+    eng = ServeEngine(cfg, batch=2, max_len=24, seed=0, enc_len=enc_len,
+                      paged=PagedConfig(fused=True))
+    pools = eng._store.device_pools()
+    state = eng._init_state()
+    tables = np.zeros((2, eng._table_width), np.int32)
+    closed = jax.make_jaxpr(eng.model.decode_step_paged)(
+        eng.params, state, pools, tables, np.zeros((2, 1), np.int32),
+        np.zeros((2,), np.int32))
+    pool_vars = set(closed.jaxpr.invars[
+        len(jax.tree.leaves(eng.params)) + len(jax.tree.leaves(state)):][
+            :len(pools)])
+    tails = {a.shape[-4:] for a in pools.values()}
+    scans = _scan_operands(closed.jaxpr)
+    assert pool_vars and pool_vars <= {v for carry, _, _ in scans
+                                       for v in carry
+                                       if type(v).__name__ != "Literal"}
+    for _, xs, ys in scans:
+        for v in (*xs, *ys):
+            assert tuple(v.aval.shape[-4:]) not in tails, (arch, v.aval)

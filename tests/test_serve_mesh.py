@@ -29,6 +29,44 @@ def _mesh24():
     return _FakeMesh((2, 4), ("data", "model"))
 
 
+@pytest.mark.parametrize("n_heads,n_kv_heads,fused", [
+    (8, 4, True),      # heads divide the model axis: KV heads on ``model``
+    (8, 2, True),      # KV heads do not: replicated, as the kernel takes them
+    (8, 4, False),     # lane-mode flat pools keep their row axis on ``model``
+])
+def test_pool_sharding_follows_kernel_heads_spec(n_heads, n_kv_heads, fused):
+    """A fused pool's KV-head axis takes exactly the ``model`` entry the
+    paged kernel's ``heads_spec`` gives its heads, so each layer's call
+    finds its pages on its own shard; the page axis is never sharded."""
+    from types import SimpleNamespace
+
+    import jax
+    from jax.sharding import AbstractMesh
+
+    from repro.kernels.common import heads_spec
+    from repro.serve import PagedKVStore
+
+    mesh = AbstractMesh((2, 4), ("data", "model"))
+    leaf = jax.ShapeDtypeStruct((3, 1, n_kv_heads, 64, 16), np.float32)
+    store = PagedKVStore({"k": leaf, "v": leaf}, {"k": 3, "v": 3},
+                         page_size=8, n_pages=8, int8=True, fused=fused)
+    eng = SimpleNamespace(mesh=mesh, _store=store, cfg=SimpleNamespace(
+        n_heads=n_heads, n_kv_heads=n_kv_heads))
+    rules = ServeEngine._pool_sharding_rules(eng)
+    assert set(rules) == {"k", "v", "k__scale", "v__scale"}
+    want = heads_spec(mesh, 2, n_heads, n_kv_heads)[1]
+    for name, sharding in rules.items():
+        spec = tuple(sharding.spec)
+        pool = (store.pools | {f"{n}__scale": a for n, a in
+                               store.scale_pools.items()})[name]
+        assert len(spec) == pool.ndim
+        if fused:
+            assert spec[-4] == want and spec.count("model") == (
+                want is not None)
+        else:
+            assert spec == ("model",) + (None,) * (pool.ndim - 1)
+
+
 # -- mesh-aware admission (no devices needed) ----------------------------------
 
 

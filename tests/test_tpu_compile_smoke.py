@@ -15,6 +15,7 @@ must all collect the same tests.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -207,6 +208,121 @@ def test_paged_decode_compiles_for_v5e(one_chip, dt, page, block_k, shape):
     assert "tsl_paged_attention" in compiled.as_text()
 
 
+@pytest.mark.parametrize("pool_shape,d,dt", [
+    ((24, 1, 16, 384, 32, 128), 64, jnp.bfloat16),    # two tokens a row
+    ((2, 1, 32, 384, 64, 128), 112, jnp.bfloat16),    # 112 lanes of 128
+    ((24, 1, 16, 384, 32, 128), 64, jnp.float32),
+    ((24, 1, 16, 384, 32, 128), 64, jnp.int8),        # an int8 payload
+], ids=["hd64", "hd112", "f32", "int8"])
+def test_paged_token_write_compiles_for_v5e(one_chip, pool_shape, d, dt):
+    """The row-write kernel on a donated stacked pool, 8 slots x a span of
+    5, its output aliased to the pool: no copy of the pool around it."""
+    from repro.kernels.paged.write import token_write
+
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        (pool_shape, dt), ((8, pool_shape[2], 5, d), dt),
+        ((8, 5), jnp.int32), ((8, 5), jnp.int32), ((), jnp.int32))]
+    text = jax.jit(lambda p, t, g, o, l: token_write(p, t, g, o, layer=l),
+                   donate_argnums=0).lower(*args).compile().as_text()
+    assert "tsl_paged_token_write" in text
+    ops = _ops_holding(text, {int(np.prod(pool_shape))})
+    assert ops <= _IN_PLACE_POOL_OPS, ops - _IN_PLACE_POOL_OPS
+
+
+# ops that may take or make a page-pool-shaped array in the compiled decode
+# program: the pools enter and leave aliased, ride the layer loop's carry,
+# and reach the row-write and attention kernels through bitcasts, the
+# row-write kernel's output aliased to its pool operand
+_IN_PLACE_POOL_OPS = {"parameter", "bitcast", "custom-call",
+                      "get-tuple-element", "tuple", "while"}
+
+
+def _ops_holding(text, sizes):
+    """The HLO ops whose result holds an array of one of ``sizes`` elements,
+    in whatever shape or view."""
+    import re
+
+    return {op for dims, op in re.findall(
+        r"\w+\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(", text)
+        if int(np.prod([int(x) for x in dims.split(",")])) in sizes}
+
+
+@pytest.mark.parametrize("arch,n_layers,slots,span,temp_mb", [
+    ("qwen1.5-0.5b", None, 8, 1, 64),    # the chat cell's decode program
+    ("qwen1.5-0.5b", None, 8, 5, 64),    # a verify span of k_max + 1 = 5
+    ("qwen1.5-0.5b", None, 24, 1, 64),   # 24 slots
+    # heads of 112 lanes, padded to a 128-lane row; two shared-attention
+    # groups. Its temporaries are the mamba weights', not checked here
+    ("zamba2-7b", 12, 8, 1, None),
+    ("whisper-tiny", None, 8, 1, 64),    # decoder self-attention, hd 64
+], ids=["qwen-decode", "qwen-verify5", "qwen-24slots", "zamba-hd112",
+        "whisper"])
+def test_fused_decode_program_moves_no_pool_for_v5e(one_chip, monkeypatch,
+                                                    arch, n_layers, slots,
+                                                    span, temp_mb):
+    """A family's whole fused decode (or verify) program at its published
+    widths, 384 pages of 64 tokens and tables of 24, on the v5e library: no
+    copy, slice or relayout of the page pools, only the in-place row-write
+    kernel and the paged attention kernel, both reading the stack by layer
+    index; the program's size does not grow with slots or span."""
+    import dataclasses
+    import re
+
+    from repro import tsl_api
+    from repro.configs import get_config
+    from repro.core import load_library
+    from repro.kernels.paged.rows import row_format
+    from repro.nn.model import build_model
+
+    monkeypatch.setattr(tsl_api, "_lib", load_library("tpu_v5e"))
+    jax.clear_caches()
+    cfg = get_config(arch)
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    model = build_model(cfg)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        model.init, jax.random.PRNGKey(0)))
+    page, n_pages, width = 64, 384, 24
+    # the serving store's fused layout: the slot axis of size 1, the token
+    # axis split into (n_pages, page // f) rows of w lanes
+    state = jax.eval_shape(lambda: model.init_decode_state(slots,
+                                                           page * width))
+    axes, slot_axes = model.state_page_axes(state), \
+        model.state_batch_axes(state)
+    tails, pools = {}, {}
+    for name, leaf in state.items():
+        if axes[name] is None:
+            tails[name] = sds(leaf.shape, leaf.dtype)
+            continue
+        row = list(leaf.shape)
+        row[slot_axes[name]] = 1
+        del row[axes[name]]
+        f, w = row_format(page, row[-1])
+        pools[name] = sds(tuple(row[:-1]) + (n_pages, page // f, w),
+                          jnp.bfloat16)
+    step_fn = model.decode_step_paged if span == 1 else \
+        model.verify_step_paged
+    try:
+        compiled = jax.jit(step_fn, donate_argnums=(1, 2)).lower(
+            params, tails, pools, sds((slots, width), jnp.int32),
+            sds((slots, span), jnp.int32),
+            sds((slots,), jnp.int32)).compile()
+    finally:
+        jax.clear_caches()
+    text = compiled.as_text()
+    assert "tsl_paged_attention" in text and "tsl_paged_token_write" in text
+    ops = _ops_holding(text, {int(np.prod(p.shape)) for p in pools.values()})
+    assert "custom-call" in ops
+    assert ops <= _IN_PLACE_POOL_OPS, ops - _IN_PLACE_POOL_OPS
+    # one write per pool leaf and layer body, whatever the slots and span
+    assert len(re.findall(r"%tsl_paged_token_write[.\d]* = \S+ custom-call\(",
+                          text)) == len(pools)
+    if temp_mb is not None:
+        # the loop's temporaries are small: no second pool anywhere
+        assert compiled.memory_analysis().temp_size_in_bytes < temp_mb << 20
+
+
 def test_range_count_compiles_for_v5e(one_chip):
     """The count once went out as a scalar store to VMEM, which the chip
     refuses."""
@@ -246,7 +362,9 @@ def _compile_on_mesh(mesh, fn, *shapes):
             for s, dt, spec in shapes]
     with jax.set_mesh(mesh):
         compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 def test_row_kernels_and_grads_compile_on_v5e_mesh(mesh):
@@ -275,13 +393,45 @@ def test_flash_grad_compiles_on_v5e_mesh(mesh):
                      *[((B, H, S, HD), jnp.bfloat16, spec)] * 3)
 
 
-def test_paged_decode_compiles_on_v5e_mesh(mesh):
-    """The serving engine shards its page pools on the page axis."""
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one-layer", "stacked"])
+def test_paged_decode_compiles_on_v5e_mesh(mesh, stacked):
+    """One layer's pool, its page axis on ``model`` (``layer=None``, the
+    UPD tests' and bench set-ups' form: moved to the kernel's heads), and
+    the serving engine's pools: a stack of layers, two tokens a row, the KV
+    heads on ``model`` where the kernel runs them, read at a layer index
+    with nothing moved between the chips."""
     from repro.kernels.paged.ops import paged_attention
 
-    pool = ((H, 48, 64, HD), jnp.bfloat16, (None, "model"))
-    _compile_on_mesh(mesh,
-                     lambda q, k, v, t, n: paged_attention(q, k, v, t, n,
-                                                           block_k=16),
-                     ((8, H, 1, HD), jnp.bfloat16, ()), pool, pool,
-                     ((8, 3), jnp.int32, ()), ((8,), jnp.int32, ()))
+    q = ((8, H, 1, HD), jnp.bfloat16, ())
+    tables = ((8, 3), jnp.int32, ())
+    kv_len = ((8,), jnp.int32, ())
+    if not stacked:
+        pool = ((H, 48, 64, HD), jnp.bfloat16, (None, "model"))
+        _compile_on_mesh(mesh,
+                         lambda q, k, v, t, n: paged_attention(q, k, v, t, n,
+                                                               block_k=16),
+                         q, pool, pool, tables, kv_len)
+        return
+    pool = ((4, 1, H, 48, 32, 2 * HD), jnp.bfloat16, (None, None, "model"))
+    text = _compile_on_mesh(
+        mesh,
+        lambda q, k, v, t, n, l: paged_attention(q, k, v, t, n, layer=l,
+                                                 block_k=16),
+        q, pool, pool, tables, kv_len, ((), jnp.int32, ()))
+    assert "all-to-all" not in text
+
+
+def test_paged_token_write_compiles_on_v5e_mesh(mesh):
+    """The row-write kernel on the engine's stacked pool, KV heads on
+    ``model``: each shard writes its own heads of every slot's tokens in
+    place, with nothing moved between the chips."""
+    from repro.kernels.paged.write import token_write
+
+    pool = ((4, 1, H, 48, 32, 2 * HD), jnp.bfloat16, (None, None, "model"))
+    text = _compile_on_mesh(
+        mesh,
+        lambda p, t, g, o, l: token_write(p, t, g, o, layer=l),
+        pool, ((8, H, 2, HD), jnp.bfloat16, ()), ((8, 2), jnp.int32, ()),
+        ((8, 2), jnp.int32, ()), ((), jnp.int32, ()))
+    assert "tsl_paged_token_write" in text and "all-to-all" not in text
